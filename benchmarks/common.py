@@ -41,11 +41,26 @@ from repro.core.netsim import (SimParams, Topology, Workload, WorkloadBuilder,
 from repro.core.netsim.topology import DEFAULT_LINK_BPS as LINK_BPS
 
 CACHE = Path(__file__).resolve().parent / ".cache.json"
+# JAX's persistent compilation cache, when JAX_COMPILATION_CACHE_DIR is
+# unset: a fixed path, because the path is part of every cache key.
+COMPILE_CACHE = Path(__file__).resolve().parents[1] / ".jax_cache"
 QUICK = os.environ.get("BENCH_QUICK", "0") != "0"
 
 # Bumped whenever the cache key scheme or result layout changes; older
 # cache files are discarded wholesale instead of serving stale entries.
 CACHE_SCHEMA = 3
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins (JAX reads it itself);
+    otherwise the cache lives at the fixed ``<repo>/.jax_cache``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE))
+    return str(COMPILE_CACHE)
 
 
 def grid_devices():

@@ -42,8 +42,8 @@ import numpy as np
 
 from repro.core.netsim import core_trace_count, metrics, resolve_grid_mesh
 
-from .common import (QUICK, build_scenario, knob_combos, knob_grid, run_grid,
-                     sweep_axes_for)
+from .common import (QUICK, build_scenario, enable_compile_cache, knob_combos,
+                     knob_grid, run_grid, sweep_axes_for)
 
 BENCH_FILE = Path(__file__).resolve().parents[1] / "BENCH_grid512.json"
 BENCH_SCHEMA = 1
@@ -219,6 +219,7 @@ def write_bench(result) -> dict:
 
 
 def main(argv) -> int:
+    enable_compile_cache()
     t0 = time.time()
     res = run()
     res["_wall_s"] = round(time.time() - t0, 1)

@@ -299,6 +299,8 @@ def main():
     ap.add_argument("--cell", required=True)
     ap.add_argument("--variant", required=True)
     args = ap.parse_args()
+    from benchmarks.common import enable_compile_cache
+    enable_compile_cache()
     res = VARIANTS[(args.cell, args.variant)]()
     data = json.loads(OUT.read_text()) if OUT.exists() else {}
     data[f"{args.cell}/{args.variant}"] = res

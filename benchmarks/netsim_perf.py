@@ -45,7 +45,7 @@ from repro.core.netsim.simulator import (_core_impl, _resolve_routing,
                                          build_static, wl_arrays)
 
 from .common import (QUICK, build_scenario, cached, default_params,
-                     kernel_tuning, knob_grid)
+                     enable_compile_cache, kernel_tuning, knob_grid)
 
 BENCH_FILE = Path(__file__).resolve().parents[1] / "BENCH_netsim.json"
 # Schema 3: adds the append-only "trajectory" list — one entry per PR
@@ -481,6 +481,7 @@ def check() -> int:
 def main(argv) -> int:
     if "--check" in argv:
         return check()
+    enable_compile_cache()
     res = bench()
     write_bench(res)
     print(json.dumps(res, indent=1))

@@ -14,6 +14,9 @@ import traceback
 
 def main() -> None:
     import importlib
+
+    from .common import enable_compile_cache
+    enable_compile_cache()
     specs = [
         ("fig2_misalignment",
          lambda r: f"baseline_overlap={r['baseline_ecmp']['max_overlap']};"

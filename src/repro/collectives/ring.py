@@ -27,11 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _axis_size(axis: str) -> int:
-    from ..compat import axis_size
-    return axis_size(axis)
-
-
 def _perm(n: int, shift: int = 1):
     return [(i, (i + shift) % n) for i in range(n)]
 
@@ -46,7 +41,7 @@ def ring_reduce_scatter(x: jax.Array, axis: str, reverse: bool = False
     an explicit collective-permute chain in HLO (the "steps" Symphony
     aligns).
     """
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -64,7 +59,7 @@ def ring_reduce_scatter(x: jax.Array, axis: str, reverse: bool = False
 def ring_all_gather(x: jax.Array, axis: str, reverse: bool = False
                     ) -> jax.Array:
     """x: [k, ...] local shard -> [n*k, ...] full, ring-pipelined."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -91,7 +86,7 @@ def ring_all_reduce(x: jax.Array, axis: str, channels: int = 1,
     channels > 1 splits into parallel rings (NCCL channels); bidirectional
     runs half the data around each ring direction.
     """
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     shape = x.shape
@@ -120,7 +115,7 @@ def ring_all_reduce_nd(x: jax.Array, axis: str) -> jax.Array:
     keep their (auto/TP) sharding, so the permute payload stays the local
     shard.  (Flattening a TP-sharded gradient first forces a 16x all-gather —
     measured in EXPERIMENTS.md §Perf iteration 3.)"""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     orig = x.shape
@@ -147,7 +142,7 @@ def hierarchical_all_reduce(x: jax.Array, inner_axis: str, outer_axis: str,
     `compress` = (encode, decode) pair applied around the inter-pod hop
     (e.g. int8 error-feedback, optim/compress.py).
     """
-    n = _axis_size(inner_axis)
+    n = jax.lax.axis_size(inner_axis)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % (n * channels)
     if pad:

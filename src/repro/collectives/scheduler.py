@@ -67,10 +67,9 @@ def sync_grads_local(grads, axes: tuple[str, ...], *, mode: str = "ring",
     leaves, treedef = jax.tree.flatten(grads)
     if not axes:
         return grads
-    from ..compat import axis_size
     n_total = 1
     for ax in axes:
-        n_total *= axis_size(ax)
+        n_total *= jax.lax.axis_size(ax)
 
     if mode == "psum":
         out = [jax.lax.psum(l, axes) for l in leaves]

@@ -34,8 +34,8 @@ Entry points
 Multi-device dispatch
 ---------------------
 ``simulate_grid(..., devices=..., mesh=...)`` shards the flattened
-``K*S`` lane axis across a 1-D device mesh via ``shard_map`` (the
-jax-0.4.37 compat spelling in :mod:`repro.compat`): every device runs
+``K*S`` lane axis across a 1-D device mesh via ``shard_map`` (through
+:func:`repro.compat.shard_map`): every device runs
 ``lanes/D`` independent simulations of the SAME compiled program, so the
 one-compile contract (``core_trace_count``) is unchanged.  Lane counts
 that don't divide the device count are padded by repeating the last lane

@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import use_interpret
+
 NEG_INF = -1e30
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -83,9 +85,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def flash_fwd(q, k, v, *, scale, window=0, causal=True, bq=DEFAULT_BQ,
-              bk=DEFAULT_BK, interpret=True):
+              bk=DEFAULT_BK, interpret=None):
     """q: [BH, S, D]; k/v: [BHkv, S, D] with BH = BHkv * group.
     Returns (o [BH,S,D], lse [BH,S])."""
+    if interpret is None:
+        interpret = use_interpret()
     BH, S, D = q.shape
     BHkv = k.shape[0]
     group = BH // BHkv
@@ -199,9 +203,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def flash_bwd(q, k, v, o, lse, do, *, scale, window=0, causal=True,
-              bq=DEFAULT_BQ, bk=DEFAULT_BK, interpret=True):
+              bq=DEFAULT_BQ, bk=DEFAULT_BK, interpret=None):
     """Returns (dq [BH,S,D], dk, dv [BH,S,D] per-q-head; caller reduces
     over GQA groups)."""
+    if interpret is None:
+        interpret = use_interpret()
     BH, S, D = q.shape
     BHkv = k.shape[0]
     group = BH // BHkv

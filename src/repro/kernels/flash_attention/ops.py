@@ -2,13 +2,11 @@
 
 Public entry `flash_attention(q, k, v, q_pos, k_pos, window=0)` matches the
 model-side calling convention ([B, S, H, D] layout, contiguous positions).
-`interpret` defaults to True because this container is CPU-only; on TPU set
-REPRO_PALLAS_INTERPRET=0.
+Interpret or compiled mode follows `repro.kernels.use_interpret`.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -16,19 +14,17 @@ import numpy as np
 
 from .kernel import flash_bwd, flash_fwd
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash(q, k, v, window, causal):
     o, _ = flash_fwd(q, k, v, scale=1.0 / np.sqrt(q.shape[-1]),
-                     window=window, causal=causal, interpret=INTERPRET)
+                     window=window, causal=causal)
     return o
 
 
 def _flash_fwd_rule(q, k, v, window, causal):
     o, lse = flash_fwd(q, k, v, scale=1.0 / np.sqrt(q.shape[-1]),
-                       window=window, causal=causal, interpret=INTERPRET)
+                       window=window, causal=causal)
     return o, (q, k, v, o, lse)
 
 
@@ -36,7 +32,7 @@ def _flash_bwd_rule(window, causal, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = flash_bwd(q, k, v, o, lse, do,
                            scale=1.0 / np.sqrt(q.shape[-1]),
-                           window=window, causal=causal, interpret=INTERPRET)
+                           window=window, causal=causal)
     group = q.shape[0] // k.shape[0]
     if group > 1:
         # dk/dv come back per-q-head; reduce over each GQA group

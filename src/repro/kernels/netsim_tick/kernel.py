@@ -10,7 +10,7 @@ on-chip, and only the tick's true inputs/outputs touch HBM.
 
 The stage functions stay the golden reference (`ref.py`): the kernel body
 replays their op sequence exactly, so in interpret mode the fused tick is
-**bit-for-bit** identical to the staged engine — the seed golden chain
+**bit-for-bit** identical to the staged engine — the golden chain
 (Table-1 finish-tick traces) holds under ``backend="pallas"``.
 
 Share policies: ``proportional`` and ``pq`` are implemented in-kernel
@@ -22,10 +22,12 @@ Segment reductions come in two flavors (``segsum=``):
 
 * ``"scatter"`` — `.at[].add/max/min`, the reference op sequence;
   bitwise-equal to the staged engine (interpret mode).
-* ``"onehot"``  — dense one-hot contractions (MXU matmul for the adds,
-  masked row reductions for min/max).  Mosaic has no vector scatter, so
-  this is the shape a compiled TPU lowering takes; adds reassociate, so
-  it is allclose-not-bitwise vs the reference.
+* ``"onehot"``  — dense one-hot masks reduced along the instance axis
+  (the monolithic kernel contracts the adds with a matmul; the tiled
+  kernel sums masked rows on the vector unit, exact f32 adds).  Mosaic
+  has no vector scatter, so this is the shape a compiled TPU lowering
+  takes; adds reassociate, so it is allclose-not-bitwise vs the
+  reference.
 
 Tiling (``blk=``): the onehot variant additionally runs as a proper grid
 kernel over the flat ``[FW]`` instance axis — ``grid = (4 sweeps,
@@ -58,18 +60,22 @@ all.  Every former gather — ``routes[inst_flow]``, the per-step ECMP
 candidate lookup, ``chunk_sched[inst_job]``, ``done_upto[inst_flow]`` —
 is replaced by *packed per-instance tables* (`params.pack_route_tables`)
 streamed block-by-block through the same BlockSpec pipeline as the
-instance state, plus iota-select-and-sum reads (`_onehot_take` /
-`_onehot_col`: exactly one selected entry per output, so the masked sum
+instance state, plus iota-select-and-sum reads (`_col_take` /
+`_sub_select`: exactly one selected entry per output, so the masked sum
 is value-exact) for the in-kernel dynamic lookups (ECMP candidate
 choice, per-link scales, Symphony rows).  Per-block valid-row counts
 ride in scalar prefetch (``PrefetchScalarGridSpec``), so block shapes
 stay static and the next block's table DMA overlaps compute.  The
 resulting TPU-platform StableHLO contains **zero** ``stablehlo.gather``
-and **zero** ``stablehlo.scatter`` ops — the full Mosaic-lowerable
-shape, CI-gated.
+and **zero** ``stablehlo.scatter`` ops.
 
-Compiled (non-interpret) execution is untested on this repo's CPU-only
-CI — `ops.use_interpret` defaults to interpret mode on CPU hosts.
+Only the tiled kernel compiles for a TPU (``tests/test_tpu_compile.py``
+compiles it for a described v5e at Table-1 and 512-host widths; the
+layout it needs is described above :func:`_tiled_tick_kernel`).  The
+monolithic kernel, in either ``segsum`` mode, gathers from whole-axis
+tables and runs in interpret mode only: `ops.fused_tick` refuses it when
+compiled.  `repro.kernels.use_interpret` picks interpret mode exactly on
+a CPU backend.
 """
 from __future__ import annotations
 
@@ -82,6 +88,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.netsim.stages import WIRE_SEG, per_hop
+from .. import use_interpret
 
 # stages.BIG as a Python int: the kernel body must not capture device
 # constants (pallas requires all array operands to be explicit inputs).
@@ -155,21 +162,12 @@ def _zero_null_link(q, L, mode):
 
 
 # ----------------------------------------------- gather-free table reads
-def _onehot_take(table, idx):
-    """Gather-free ``table[idx]`` for a 1-D table: iota-select-and-sum
-    over the table axis.  Exactly one entry is selected per output, so
-    the masked sum is value-exact (``x + 0 == x``) — bitwise-equal to
-    the gather for ints and for the non-negative floats used here."""
-    flat = idx.reshape(-1)
-    oh = _rows(table.shape[0], flat.shape[0]) == flat[None, :]
-    out = jnp.where(oh, table[:, None], 0).sum(axis=0)
-    return out.reshape(idx.shape)
-
-
 def _onehot_col(table, idx):
     """Gather-free row-wise column select: ``table[arange(N), idx]`` for
-    a ``[N, C]`` table and ``[N]`` indices.  Same exactness contract as
-    :func:`_onehot_take`."""
+    a ``[N, C]`` table and ``[N]`` indices.  Exactly one entry is
+    selected per output, so the masked sum is value-exact (``x + 0 ==
+    x``) — bitwise-equal to the gather for ints and for the non-negative
+    floats used here."""
     oh = (jax.lax.broadcasted_iota(jnp.int32, table.shape, 1)
           == idx[:, None])
     return jnp.where(oh, table, 0).sum(axis=1)
@@ -371,17 +369,107 @@ def _tick_kernel(step_ref, sent_ref, rate_ref, done_ref, q_ref,
 
 
 # ----------------------------------------------------- tiled kernel body
-def _tiled_tick_kernel(*refs, H, SEG, blk, dt, mtu, per_step_ecmp, policy):
+# Mosaic tiles the last two dims of every block by (8, 128): a block dim
+# must be a multiple of the tile or the whole array dim.  The tiled
+# kernel therefore lays its operands out as
+#   per-instance vectors   (1, FW)   lane rows, ``(1, blk)`` blocks
+#   per-instance tables    (K, FW)   transposed, ``(K, blk)`` blocks
+#   link / Symphony state  (R, 1)    sublane columns, whole-array blocks
+#   traced scalars         (1, n)    SMEM
+# so every block stays legal when ``vmap`` prepends a squeezed lane dim.
+# Segment reductions compare a row of ids against a sublane iota of the
+# target rows: ``(rows, blk)`` masks, reduced along lanes for scatters
+# onto a column and along sublanes for reads of a column.
+
+# target rows per reduction step; bounds the mask (and the unrolled code)
+# at any link count
+ROW_CHUNK = 128
+
+
+def _pad_rows(n: int) -> int:
+    """Rows of a column operand: sublane-aligned, whole ``ROW_CHUNK``s."""
+    if n <= ROW_CHUNK:
+        return -(-n // 8) * 8
+    return -(-n // ROW_CHUNK) * ROW_CHUNK
+
+
+def _row_loop(n_rows, body, init):
+    """Run ``body(r0, lc, carry)`` over the ``lc``-row chunks of ``n_rows``."""
+    lc = min(n_rows, ROW_CHUNK)
+    n = n_rows // lc
+    if n == 1:
+        return body(0, lc, init)
+    return jax.lax.fori_loop(
+        0, n, lambda c, carry: body(pl.multiple_of(c * lc, lc), lc, carry),
+        init)
+
+
+def _col_take(cols, idx):
+    """``[col[idx] for col in cols]`` for ``(R, 1)`` column refs and a
+    ``(1, blk)`` row of row ids.  Exactly one row matches each id, so the
+    masked sum is value-exact."""
+    blk = idx.shape[1]
+
+    def body(r0, lc, accs):
+        hit = (r0 + jax.lax.broadcasted_iota(jnp.int32, (lc, blk), 0)) == idx
+        return tuple(
+            a + jnp.sum(jnp.where(hit, c[pl.ds(r0, lc), :], 0), axis=0,
+                        keepdims=True)
+            for a, c in zip(accs, cols))
+
+    return _row_loop(cols[0].shape[0], body,
+                     tuple(jnp.zeros((1, blk), c.dtype) for c in cols))
+
+
+_REDUCE = {"add": (jnp.sum, jnp.add), "max": (jnp.max, jnp.maximum),
+           "min": (jnp.min, jnp.minimum)}
+
+
+def _neutral(op, dtype):
+    if op == "add":
+        return 0
+    info = (jnp.finfo if jnp.issubdtype(dtype, jnp.floating) else jnp.iinfo)(
+        dtype)
+    return info.min if op == "max" else info.max
+
+
+def _col_scatter(acc, op, pairs):
+    """``acc[idx] op= vals`` for every ``(idx, vals)`` pair of ``(1, blk)``
+    rows, accumulated into the ``(R, 1)`` column ref ``acc``."""
+    red, comb = _REDUCE[op]
+    neutral = _neutral(op, acc.dtype)
+    blk = pairs[0][0].shape[1]
+
+    def body(r0, lc, carry):
+        ids = r0 + jax.lax.broadcasted_iota(jnp.int32, (lc, blk), 0)
+        part = acc[pl.ds(r0, lc), :]
+        for idx, vals in pairs:
+            part = comb(part, red(jnp.where(ids == idx, vals, neutral),
+                                  axis=1, keepdims=True))
+        acc[pl.ds(r0, lc), :] = part
+        return carry
+
+    _row_loop(acc.shape[0], body, 0)
+
+
+def _sub_select(slab, choice):
+    """Row ``choice[i]`` of a ``(P, blk)`` slab, per lane ``i``."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, slab.shape, 0) == choice
+    return jnp.sum(jnp.where(hit, slab, 0), axis=0, keepdims=True)
+
+
+def _tiled_tick_kernel(*refs, H, P, J, SEG, L, blk, dt, mtu, per_step_ecmp,
+                       policy):
     """One tick, tiled over the instance axis: grid = (sweep, block).
 
     Gather-free: per-instance refs — including the packed route/chunk/
-    ECMP tables — hold one ``blk``-row block (BlockSpec-sliced); link/
-    Symphony refs hold whole arrays; there are no index-table operands
+    ECMP tables — hold one ``blk``-lane block (BlockSpec-sliced); link/
+    Symphony refs hold whole columns; there are no index-table operands
     left to gather from.  ``refs[0]`` is the scalar-prefetch ref with
-    the per-block valid-row counts (the only trace-time metadata the
-    blocks need — keeping it lane-invariant is what lets ``vmap`` batch
-    the lane axis into this one ``pallas_call``).  The scratch refs
-    persist across grid steps and carry the cross-block partials.
+    the per-block valid counts (the only trace-time metadata the blocks
+    need — keeping it lane-invariant is what lets ``vmap`` batch the
+    lane axis into this one ``pallas_call``).  The scratch refs persist
+    across grid steps and carry the cross-block partials.
     """
     nroute = 3 if per_step_ecmp else 2
     n_in = 20 + nroute + 2
@@ -410,26 +498,21 @@ def _tiled_tick_kernel(*refs, H, SEG, blk, dt, mtu, per_step_ecmp, policy):
     isent = sent_ref[...]
     irate = rate_ref[...]
     inst_job = job_ref[...]
-    inst_flow = flow_ref[...]
     sps = sps_ref[...]
-    phase = phase_ref[...]
-    nph = nph_ref[...]
-    off = off_ref[...]
-    cap = cap_ref[...]
-    tick, seed = iscal_ref[0], iscal_ref[1]
-    bg_period, sym_win, pq_on = iscal_ref[2], iscal_ref[3], iscal_ref[4]
-    bg_duty = fscal_ref[0]
-    red_kmin, red_kmax, red_pmax = fscal_ref[1], fscal_ref[2], fscal_ref[3]
-    tau, n_sample, alpha_max = fscal_ref[4], fscal_ref[5], fscal_ref[6]
-    J = jobmin_s.shape[0]
-    DJ = smin_ref.shape[0]
-    L = cap.shape[0] - 1
+    tick, seed = iscal_ref[0, 0], iscal_ref[0, 1]
+    bg_period, sym_win, pq_on = iscal_ref[0, 2], iscal_ref[0, 3], \
+        iscal_ref[0, 4]
+    bg_duty = fscal_ref[0, 0]
+    red_kmin, red_kmax, red_pmax = fscal_ref[0, 1], fscal_ref[0, 2], \
+        fscal_ref[0, 3]
+    tau, n_sample, alpha_max = fscal_ref[0, 4], fscal_ref[0, 5], \
+        fscal_ref[0, 6]
 
-    # ---- per-block instance view; edge-padded rows are masked inactive
-    valid = jax.lax.broadcasted_iota(jnp.int32, (blk,), 0) < nvalid_ref[b]
-    iseg = (istep // sps) * nph + phase
-    ichunk = _onehot_col(chunk_ref[...], jnp.clip(iseg, 0, SEG - 1))
-    iwire = iseg * WIRE_SEG + istep % sps + off
+    # ---- per-block instance view; edge-padded lanes are masked inactive
+    valid = jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1) < nvalid_ref[b]
+    iseg = (istep // sps) * nph_ref[...] + phase_ref[...]
+    ichunk = _sub_select(chunk_ref[...], jnp.clip(iseg, 0, SEG - 1))
+    iwire = iseg * WIRE_SEG + istep % sps + off_ref[...]
     occupied = istep >= 0
     retired = occupied & (istep < done_ref[...])
     complete = occupied & (isent >= ichunk)
@@ -438,144 +521,147 @@ def _tiled_tick_kernel(*refs, H, SEG, blk, dt, mtu, per_step_ecmp, policy):
 
     if per_step_ecmp:
         cand_ref, cdom_ref, npaths_ref = route_refs
-        h = (inst_flow.astype(jnp.uint32) * jnp.uint32(2654435761)
+        h = (flow_ref[...].astype(jnp.uint32) * jnp.uint32(2654435761)
              + jnp.maximum(istep, 0).astype(jnp.uint32) * jnp.uint32(40503)
              + (seed.astype(jnp.uint32) + 1) * jnp.uint32(2246822519))
         h = (h ^ (h >> 13)) * jnp.uint32(2654435761)
         h = h ^ (h >> 16)
-        n_p = npaths_ref[...].astype(jnp.uint32)
-        choice = (h % n_p).astype(jnp.int32)
-        iroute = _onehot_plane(cand_ref[...], choice)
-        idom = _onehot_plane(cdom_ref[...], choice)
+        choice = (h % npaths_ref[...].astype(jnp.uint32)).astype(jnp.int32)
+        # candidate slabs hold hop k of every path in rows [k*P, k*P + P)
+        routes = [_sub_select(cand_ref[k * P:(k + 1) * P, :], choice)
+                  for k in range(H)]
+        doms = [_sub_select(cdom_ref[k * P:(k + 1) * P, :], choice)
+                for k in range(H)]
     else:
         routes_ref, rdom_ref = route_refs
-        iroute = routes_ref[...]
-        idom = rdom_ref[...]
-    flat_links = iroute.reshape(-1)
+        routes = [routes_ref[k:k + 1, :] for k in range(H)]
+        doms = [rdom_ref[k:k + 1, :] for k in range(H)]
     w_rate = jnp.where(active, irate, 0.0)
 
     bg_on = (tick % bg_period).astype(jnp.float32) < \
         bg_duty * bg_period.astype(jnp.float32)
-    bg = bgb_ref[...] + jnp.where(bg_on, bga_ref[...], 0.0)
 
-    def block_lsum(acc, vals):
-        return _segadd(acc, flat_links, per_hop(vals, H), "onehot")
+    def bg():
+        return bgb_ref[...] + jnp.where(bg_on, bga_ref[...], 0.0)
+
+    def link_add(acc, vals):
+        _col_scatter(acc, "add", [(r, vals) for r in routes])
 
     @pl.when((s == 0) & (b == 0))
     def _init():
-        jobmin_s[...] = jnp.full(J, _BIG, jnp.int32)
-        offp_s[...] = jnp.zeros(L + 1, jnp.float32)
-        offhi_s[...] = jnp.zeros(L + 1, jnp.float32)
-        offlo_s[...] = jnp.zeros(L + 1, jnp.float32)
-        cnt_s[...] = jnp.zeros(DJ, jnp.float32)
-        cntop_s[...] = jnp.zeros(DJ, jnp.float32)
-        cand_s[...] = jnp.zeros(DJ, jnp.int32)
-        minact_s[...] = jnp.full(DJ, _BIG, jnp.int32)
-        psnwin_s[...] = jnp.zeros(DJ, jnp.float32)
+        jobmin_s[...] = jnp.full(jobmin_s.shape, _BIG, jnp.int32)
+        for r in (offp_s, offhi_s, offlo_s, cnt_s, cntop_s, psnwin_s):
+            r[...] = jnp.zeros(r.shape, jnp.float32)
+        cand_s[...] = jnp.zeros(cand_s.shape, jnp.int32)
+        minact_s[...] = jnp.full(minact_s.shape, _BIG, jnp.int32)
 
     # ---- sweep 0: job min-wire + proportional offered-load partials
     @pl.when(s == 0)
     def _sweep0():
-        jobmin_s[...] = _segmin(jobmin_s[...], inst_job,
-                                jnp.where(active, iwire, _BIG), "onehot")
-        offp_s[...] = block_lsum(offp_s[...], w_rate)
+        _col_scatter(jobmin_s, "min",
+                     [(inst_job, jnp.where(active, iwire, _BIG))])
+        link_add(offp_s, w_rate)
+
+    def hi_class():
+        (jmin,) = _col_take((jobmin_s,), inst_job)
+        return active & (iwire <= jmin)
 
     # ---- sweep 1: hi/lo-class offered partials (min-wire now complete)
     @pl.when(s == 1)
     def _sweep1():
-        is_hi = active & (iwire <= _onehot_take(jobmin_s[...], inst_job))
-        offhi_s[...] = block_lsum(offhi_s[...], jnp.where(is_hi, irate, 0.0))
-        offlo_s[...] = block_lsum(offlo_s[...],
-                                  jnp.where(active & ~is_hi, irate, 0.0))
+        is_hi = hi_class()
+        link_add(offhi_s, jnp.where(is_hi, irate, 0.0))
+        link_add(offlo_s, jnp.where(active & ~is_hi, irate, 0.0))
 
     # ---- sweep 2, first block: finalize the per-link scale factors
     @pl.when((s == 2) & (b == 0))
     def _scales():
-        off_p = offp_s[...] + bg
+        cap = cap_ref[...]
+        off_p = offp_s[...] + bg()
         sl_s[...] = jnp.minimum(1.0, cap / jnp.maximum(off_p, 1.0))
-        off_hi = offhi_s[...] + bg
+        off_hi = offhi_s[...] + bg()
         s_hi = jnp.minimum(1.0, cap / jnp.maximum(off_hi, 1.0))
         shi_s[...] = s_hi
         rem = jnp.maximum(cap - off_hi * s_hi, 0.0)
         slo_s[...] = rem / jnp.maximum(offlo_s[...], 1.0)
 
     def eff_block():
-        is_hi = active & (iwire <= _onehot_take(jobmin_s[...], inst_job))
-        eff_p = w_rate * _onehot_take(sl_s[...], iroute).min(axis=1)
-        share = jnp.where(is_hi[:, None], _onehot_take(shi_s[...], iroute),
-                          jnp.minimum(1.0, _onehot_take(slo_s[...], iroute)))
-        eff_q = w_rate * share.min(axis=1)
+        is_hi = hi_class()
+        path_p = path_hi = path_lo = None
+        for r in routes:
+            t_l, t_hi, t_lo = _col_take((sl_s, shi_s, slo_s), r)
+            share = jnp.where(is_hi, t_hi, jnp.minimum(1.0, t_lo))
+            path_p = t_l if path_p is None else jnp.minimum(path_p, t_l)
+            path_hi = share if path_hi is None else jnp.minimum(path_hi,
+                                                                share)
+        eff_p = w_rate * path_p
+        eff_q = w_rate * path_hi
         if policy == "pq":
             return eff_q
         return jnp.where(pq_on != 0, eff_q, eff_p)
 
-    def dj_block():
-        dj = idom * J + inst_job[:, None]
-        return dj, dj.reshape(-1)
+    def dj_rows():
+        return [d * J + inst_job for d in doms]
 
     # ---- sweep 2, per block: eff + Symphony cnt/cntop/step-min partials
     @pl.when(s == 2)
     def _sweep2():
         eff = eff_block()
-        dj, djf = dj_block()
-        sm4 = _onehot_take(smin_ref[...], dj).reshape(-1)
+        djs = dj_rows()
         pkts = eff * dt / mtu
         newly_done = active & (isent + eff * dt >= ichunk)
-        act4 = per_hop(active, H)
-        done4 = per_hop(newly_done, H)
-        wire4 = per_hop(iwire, H)
-        pkts4 = per_hop(pkts, H)
-        cnt_s[...] = _segadd(cnt_s[...], djf,
-                             jnp.where(act4, pkts4, 0.0), "onehot")
-        cntop_s[...] = _segadd(cntop_s[...], djf,
-                               jnp.where(act4 & (wire4 > sm4), pkts4, 0.0),
-                               "onehot")
-        cand_s[...] = _segmax(cand_s[...], djf,
-                              jnp.where(done4, wire4 + 1, 0), "onehot")
-        minact_s[...] = _segmin(minact_s[...], djf,
-                                jnp.where(act4 & ~done4, wire4, _BIG),
-                                "onehot")
+        act_pk = jnp.where(active, pkts, 0.0)
+        sms = [_col_take((smin_ref,), dj)[0] for dj in djs]
+        _col_scatter(cnt_s, "add", [(dj, act_pk) for dj in djs])
+        _col_scatter(cntop_s, "add",
+                     [(dj, jnp.where(active & (iwire > sm), pkts, 0.0))
+                      for dj, sm in zip(djs, sms)])
+        done_w = jnp.where(newly_done, iwire + 1, 0)
+        _col_scatter(cand_s, "max", [(dj, done_w) for dj in djs])
+        open_w = jnp.where(active & ~newly_done, iwire, _BIG)
+        _col_scatter(minact_s, "min", [(dj, open_w) for dj in djs])
 
     # ---- sweep 3, first block: finalize the Symphony step-min
     @pl.when((s == 3) & (b == 0))
     def _stepmin():
         cand = jnp.maximum(smin_ref[...], cand_s[...])
-        stepmin_s[...] = jnp.where(minact_s[...] < _BIG,
-                                   jnp.minimum(cand, minact_s[...]), cand)
+        minact = minact_s[...]
+        stepmin_s[...] = jnp.where(minact < _BIG, jnp.minimum(cand, minact),
+                                   cand)
 
     # ---- sweep 3, per block: psn-window partials + per-instance outputs
     @pl.when(s == 3)
     def _sweep3():
         eff = eff_block()
-        _, djf = dj_block()
         pkts = eff * dt / mtu
         newly_done = active & (isent + eff * dt >= ichunk)
-        send4 = per_hop(active & (eff > 1.0), H)
-        done4 = per_hop(newly_done, H)
-        wire4 = per_hop(iwire, H)
-        psn4 = per_hop(ipsn + pkts, H)
+        send = active & (eff > 1.0) & ~newly_done
+        psn = ipsn + pkts
         # state psn-window is always >= 0, so accumulating the >= 0
         # partials from 0 and max-ing with the state at the flush equals
         # the untiled segmax against the state directly
-        psnwin_s[...] = _segmax(psnwin_s[...], djf,
-                                jnp.where(send4 & ~done4 &
-                                          (wire4 ==
-                                           _onehot_take(stepmin_s[...], djf)),
-                                          psn4, 0.0), "onehot")
-        iroute_o[...] = iroute
+        pairs = []
+        for dj in dj_rows():
+            (smin,) = _col_take((stepmin_s,), dj)
+            pairs.append((dj, jnp.where(send & (iwire == smin), psn, 0.0)))
+        _col_scatter(psnwin_s, "max", pairs)
+        for k, r in enumerate(routes):
+            iroute_o[k:k + 1, :] = r
         eff_o[...] = eff
 
     # ---- last grid step: flush the link/Symphony outputs
     @pl.when((s == 3) & (b == nb - 1))
     def _flush():
-        off_p = offp_s[...] + bg
-        off_q = (offhi_s[...] + bg) + offlo_s[...]
+        cap = cap_ref[...]
+        off_p = offp_s[...] + bg()
+        off_q = (offhi_s[...] + bg()) + offlo_s[...]
         if policy == "pq":
             offered = off_q
         else:
             offered = jnp.where(pq_on != 0, off_q, off_p)
         q = jnp.maximum(q_ref[...] + (offered - cap) * dt, 0.0)
-        q = _zero_null_link(q, L, "onehot")
+        q = jnp.where(jax.lax.broadcasted_iota(jnp.int32, q.shape, 0) == L,
+                      0.0, q)
         offered_o[...] = offered
         q_o[...] = q
         pred_o[...] = jnp.clip((q - red_kmin) / (red_kmax - red_kmin),
@@ -604,6 +690,111 @@ def _edge_pad(x, n):
     return jnp.pad(x, [(0, n)] + [(0, 0)] * (x.ndim - 1), mode="edge")
 
 
+def _tiled_tick(operands, tables, *, FW, H, L1, DJ, J, SEG, blk, dt, mtu,
+                per_step_ecmp, policy, interpret):
+    """Dispatch the tiled grid kernel; see :func:`netsim_tick`."""
+    (step_of, sent, rate, done_upto, q_prev,
+     s_stepmin, s_psnwin, s_alpha, s_cnt, s_cntop,
+     _routes, _path_table, _n_paths, cap, _link_dom, bg_base, bg_amp,
+     inst_job, inst_flow, sps_i, phase_i, nph_i, off_i,
+     _chunk_sched, iscal, fscal) = operands
+    NB = -(-FW // blk)
+    FWp = NB * blk
+    pad = FWp - FW
+    L1p, DJp, Jp = _pad_rows(L1), _pad_rows(DJ), _pad_rows(J)
+
+    def row(x):                        # [FW] -> (1, FWp)
+        return _edge_pad(x, pad).reshape(1, FWp)
+
+    def slab(x):                       # [FW, ...] -> (K, FWp), K = hops..
+        x = _edge_pad(x, pad)
+        return x.reshape(FWp, -1).T if x.ndim == 2 else \
+            jnp.transpose(x, (2, 1, 0)).reshape(-1, FWp)
+
+    def col(x, n):                     # [R] -> (n, 1), zero rows appended
+        return jnp.pad(x, (0, n - x.shape[0])).reshape(n, 1)
+
+    # done_upto expands [F] -> [FW] at trace time (repeat = broadcast +
+    # reshape, gather-free) so it streams with the instance blocks.
+    done_i = jnp.repeat(done_upto, FW // int(done_upto.shape[0]))
+    ins = [row(step_of), row(sent), row(rate), row(done_i),
+           col(q_prev, L1p), col(s_stepmin, DJp), col(s_psnwin, DJp),
+           col(s_alpha, DJp), col(s_cnt, DJp), col(s_cntop, DJp),
+           col(cap, L1p), col(bg_base, L1p), col(bg_amp, L1p),
+           row(inst_job), row(inst_flow), row(sps_i), row(phase_i),
+           row(nph_i), row(off_i), slab(tables.chunk)]
+    if per_step_ecmp:
+        ins += [slab(tables.cand), slab(tables.cand_dom),
+                row(tables.n_paths)]
+    else:
+        ins += [slab(tables.routes), slab(tables.route_dom)]
+    ins += [iscal[None], fscal[None]]
+
+    # Per-block valid-lane counts, built from Python ints: lane-INVARIANT,
+    # which is what keeps vmap's pallas batching rule on the
+    # grid-prepend path (batched scalar-prefetch operands would fall
+    # back to a scan over lanes).
+    nvalid = jnp.asarray([min(blk, FW - i * blk) for i in range(NB)],
+                         jnp.int32)
+
+    def blocked(a):                    # (K, FWp) tiled along lanes
+        return pl.BlockSpec((a.shape[0], blk), lambda s, b, nv: (0, b))
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda s, b, nv: (0, 0))
+
+    def smem(a):
+        return pl.BlockSpec(a.shape, lambda s, b, nv: (0, 0),
+                            memory_space=pltpu.SMEM)
+
+    in_specs = [blocked(a) if a.shape[1] == FWp else whole(a)
+                for a in ins[:-2]] + [smem(a) for a in ins[-2:]]
+    out_shape = (
+        [jax.ShapeDtypeStruct((H, FWp), jnp.int32),     # iroute
+         jax.ShapeDtypeStruct((1, FWp), jnp.float32)]   # eff
+        + [jax.ShapeDtypeStruct((L1p, 1), jnp.float32)] * 3
+        + [jax.ShapeDtypeStruct((DJp, 1), jnp.int32)]
+        + [jax.ShapeDtypeStruct((DJp, 1), jnp.float32)] * 4)
+    out_specs = [blocked(o) if o.shape[1] == FWp else whole(o)
+                 for o in out_shape]
+    kernel = functools.partial(
+        _tiled_tick_kernel, H=H, P=int(tables.cand.shape[1]), J=J, SEG=SEG,
+        L=L1 - 1, blk=blk, dt=float(dt), mtu=float(mtu), per_step_ecmp=bool(per_step_ecmp),
+        policy=policy)
+    f32, i32 = jnp.float32, jnp.int32
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(TILED_SWEEPS, NB),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((Jp, 1), i32),      # jobmin
+            pltpu.VMEM((L1p, 1), f32),     # off_p partials
+            pltpu.VMEM((L1p, 1), f32),     # off_hi partials
+            pltpu.VMEM((L1p, 1), f32),     # off_lo partials
+            pltpu.VMEM((L1p, 1), f32),     # s_l scale
+            pltpu.VMEM((L1p, 1), f32),     # s_hi scale
+            pltpu.VMEM((L1p, 1), f32),     # s_lo scale
+            pltpu.VMEM((DJp, 1), f32),     # cnt partials
+            pltpu.VMEM((DJp, 1), f32),     # cntop partials
+            pltpu.VMEM((DJp, 1), i32),     # cand partials
+            pltpu.VMEM((DJp, 1), i32),     # min-active partials
+            pltpu.VMEM((DJp, 1), i32),     # finalized step-min
+            pltpu.VMEM((DJp, 1), f32),     # psn-window partials
+        ],
+    )
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(nvalid, *ins)
+    iroute, eff = outs[0][:, :FW].T, outs[1][0, :FW]
+    links = [o[:L1, 0] for o in outs[2:5]]
+    sym = [o[:DJ, 0] for o in outs[5:]]
+    return TickOut(iroute, eff, *links, *sym)
+
+
 # --------------------------------------------------------- entry point
 def netsim_tick(step_of, sent, rate, done_upto, q_prev,
                 s_stepmin, s_psnwin, s_alpha, s_cnt, s_cntop,
@@ -613,7 +804,7 @@ def netsim_tick(step_of, sent, rate, done_upto, q_prev,
                 dt: float, mtu: float, per_step_ecmp: bool,
                 policy: str = "proportional", segsum: str = "scatter",
                 blk: int | None = None, tables=None,
-                interpret: bool = True) -> TickOut:
+                interpret: bool | None = None) -> TickOut:
     """One fused tick of the netsim hot path.
 
     Per-instance state is flat ``[FW]``; link state ``[L+1]``; Symphony
@@ -624,15 +815,18 @@ def netsim_tick(step_of, sent, rate, done_upto, q_prev,
     are compile-time (from :class:`SimStructure`).
 
     ``blk`` < FW selects the tiled grid kernel (``segsum="onehot"``
-    only): per-instance operands are BlockSpec-tiled into ``blk``-row
+    only): per-instance operands are BlockSpec-tiled into ``blk``-lane
     blocks and the grid runs ``(TILED_SWEEPS, ceil(FW/blk))`` steps with
     cross-block reduction partials in persistent scratch.  The tiled
     kernel is gather-free and requires ``tables`` (a
     `params.PackedTables`, normally ``ctx.tables`` from
     `stages.make_ctx`): the packed per-instance route/chunk/ECMP tables
     are streamed block-by-block in place of the index-table operands,
-    and the per-block valid-row counts ride in scalar prefetch.
+    and the per-block valid counts ride in scalar prefetch.  Compiled
+    for a TPU, ``blk`` must be a multiple of 128 (the lane tile).
     """
+    if interpret is None:
+        interpret = use_interpret()
     if policy not in ("proportional", "pq"):
         raise ValueError(f"kernel share policy must be proportional|pq, "
                          f"got {policy!r}")
@@ -643,6 +837,11 @@ def netsim_tick(step_of, sent, rate, done_upto, q_prev,
     H = routes.shape[-1]
     L1 = cap.shape[0]
     DJ = s_stepmin.shape[0]
+    operands = (step_of, sent, rate, done_upto, q_prev,
+                s_stepmin, s_psnwin, s_alpha, s_cnt, s_cntop,
+                routes, path_table, n_paths, cap, link_dom, bg_base, bg_amp,
+                inst_job, inst_flow, sps_i, phase_i, nph_i, off_i,
+                chunk_sched, iscal, fscal)
     if blk is not None:
         if segsum != "onehot":
             raise ValueError(
@@ -650,11 +849,20 @@ def netsim_tick(step_of, sent, rate, done_upto, q_prev,
                 f"vector scatter), got segsum={segsum!r}")
         if blk < 1:
             raise ValueError(f"blk must be >= 1, got {blk}")
-    operands = (step_of, sent, rate, done_upto, q_prev,
-                s_stepmin, s_psnwin, s_alpha, s_cnt, s_cntop,
-                routes, path_table, n_paths, cap, link_dom, bg_base, bg_amp,
-                inst_job, inst_flow, sps_i, phase_i, nph_i, off_i,
-                chunk_sched, iscal, fscal)
+    if blk is not None and blk < FW:
+        if tables is None:
+            raise ValueError(
+                f"blk={blk} tiling requires packed route tables "
+                "(params.PackedTables; use ctx.tables from "
+                "stages.make_ctx): the gather-free tiled kernel streams "
+                "per-instance tables instead of gathering from index-table "
+                "operands")
+        return _tiled_tick(
+            operands, tables, FW=FW, H=H, L1=L1, DJ=DJ,
+            J=int(chunk_sched.shape[0]), SEG=int(chunk_sched.shape[-1]),
+            blk=int(blk), dt=dt, mtu=mtu, per_step_ecmp=per_step_ecmp,
+            policy=policy, interpret=interpret)
+
     out_shape = [
         jax.ShapeDtypeStruct((FW, H), jnp.int32),   # iroute
         jax.ShapeDtypeStruct((FW,), jnp.float32),   # eff
@@ -667,103 +875,10 @@ def netsim_tick(step_of, sent, rate, done_upto, q_prev,
         jax.ShapeDtypeStruct((DJ,), jnp.float32),   # s_cnt
         jax.ShapeDtypeStruct((DJ,), jnp.float32),   # s_cntop
     ]
-    if blk is None or blk >= FW:
-        kernel = functools.partial(
-            _tick_kernel, H=H, SEG=int(chunk_sched.shape[-1]), dt=float(dt),
-            mtu=float(mtu), per_step_ecmp=bool(per_step_ecmp), policy=policy,
-            segsum=segsum)
-        outs = pl.pallas_call(kernel, out_shape=out_shape,
-                              interpret=interpret)(*operands)
-        return TickOut(*outs)
-
-    # ---------- tiled dispatch: grid over (sweep, instance block)
-    if tables is None:
-        raise ValueError(
-            f"blk={blk} tiling requires packed route tables "
-            "(params.PackedTables; use ctx.tables from stages.make_ctx): "
-            "the gather-free tiled kernel streams per-instance tables "
-            "instead of gathering from index-table operands")
-    blk = int(blk)
-    NB = -(-FW // blk)
-    pad = NB * blk - FW
-    J = int(chunk_sched.shape[0])
-
-    def pad_i(x):                      # [FW, ...] -> [NB*blk, ...]
-        return _edge_pad(x, pad)
-
-    # done_upto expands [F] -> [FW] at trace time (repeat = broadcast +
-    # reshape, gather-free) so it streams with the instance blocks.
-    done_i = jnp.repeat(done_upto, FW // int(done_upto.shape[0]))
-    operands = [pad_i(step_of), pad_i(sent), pad_i(rate), pad_i(done_i),
-                q_prev, s_stepmin, s_psnwin, s_alpha, s_cnt, s_cntop,
-                cap, bg_base, bg_amp,
-                pad_i(inst_job), pad_i(inst_flow), pad_i(sps_i),
-                pad_i(phase_i), pad_i(nph_i), pad_i(off_i),
-                pad_i(tables.chunk)]
-    if per_step_ecmp:
-        operands += [pad_i(tables.cand), pad_i(tables.cand_dom),
-                     pad_i(tables.n_paths)]
-    else:
-        operands += [pad_i(tables.routes), pad_i(tables.route_dom)]
-    nroute = 3 if per_step_ecmp else 2
-    operands += [iscal, fscal]
-
-    # Per-block valid-row counts, built from Python ints: lane-INVARIANT,
-    # which is what keeps vmap's pallas batching rule on the
-    # grid-prepend path (batched scalar-prefetch operands would fall
-    # back to a scan over lanes).
-    nvalid = jnp.asarray([min(blk, FW - i * blk) for i in range(NB)],
-                         jnp.int32)
-
-    def blk_spec(a):                   # blocked per-instance operand
-        return pl.BlockSpec((blk,) + a.shape[1:],
-                            lambda s, b, nv: (b,) + (0,) * (a.ndim - 1))
-
-    def full_spec(a):                  # whole-array operand
-        return pl.BlockSpec(a.shape, lambda s, b, nv, nd=a.ndim: (0,) * nd)
-
-    blocked = set(range(4)) | set(range(13, 20 + nroute))
-    in_specs = [blk_spec(a) if i in blocked else full_spec(a)
-                for i, a in enumerate(operands)]
-    out_shape_t = list(out_shape)
-    out_shape_t[0] = jax.ShapeDtypeStruct((NB * blk, H), jnp.int32)
-    out_shape_t[1] = jax.ShapeDtypeStruct((NB * blk,), jnp.float32)
-    out_specs = [
-        pl.BlockSpec((blk, H), lambda s, b, nv: (b, 0)),    # iroute
-        pl.BlockSpec((blk,), lambda s, b, nv: (b,)),        # eff
-    ] + [full_spec(sh) for sh in out_shape_t[2:]]
     kernel = functools.partial(
-        _tiled_tick_kernel, H=H, SEG=int(chunk_sched.shape[-1]),
-        blk=blk, dt=float(dt), mtu=float(mtu),
-        per_step_ecmp=bool(per_step_ecmp), policy=policy)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(TILED_SWEEPS, NB),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((J,), jnp.int32),        # jobmin
-            pltpu.VMEM((L1,), jnp.float32),     # off_p partials
-            pltpu.VMEM((L1,), jnp.float32),     # off_hi partials
-            pltpu.VMEM((L1,), jnp.float32),     # off_lo partials
-            pltpu.VMEM((L1,), jnp.float32),     # s_l scale
-            pltpu.VMEM((L1,), jnp.float32),     # s_hi scale
-            pltpu.VMEM((L1,), jnp.float32),     # s_lo scale
-            pltpu.VMEM((DJ,), jnp.float32),     # cnt partials
-            pltpu.VMEM((DJ,), jnp.float32),     # cntop partials
-            pltpu.VMEM((DJ,), jnp.int32),       # cand partials
-            pltpu.VMEM((DJ,), jnp.int32),       # min-active partials
-            pltpu.VMEM((DJ,), jnp.int32),       # finalized step-min
-            pltpu.VMEM((DJ,), jnp.float32),     # psn-window partials
-        ],
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape_t,
-        interpret=interpret,
-    )(nvalid, *operands)
-    outs = list(outs)
-    outs[0] = outs[0][:FW]
-    outs[1] = outs[1][:FW]
+        _tick_kernel, H=H, SEG=int(chunk_sched.shape[-1]), dt=float(dt),
+        mtu=float(mtu), per_step_ecmp=bool(per_step_ecmp), policy=policy,
+        segsum=segsum)
+    outs = pl.pallas_call(kernel, out_shape=out_shape,
+                          interpret=interpret)(*operands)
     return TickOut(*outs)

@@ -7,17 +7,17 @@ Pallas kernel and composes the remaining cheap stages (marking, progress,
 rate control, segment barriers, metrics) around it on the XLA side —
 bit-for-bit equal to `stages.engine_tick_xla` in interpret mode.
 
-``REPRO_PALLAS_INTERPRET=0|1`` forces compiled/interpret execution;
-unset, interpret mode is chosen automatically on CPU hosts (Pallas TPU
-kernels cannot compile there; interpreted, the kernel traces into the
-same XLA program as the staged engine, so this is a correctness path —
-the perf win needs a real accelerator).
+Interpret or compiled: :func:`use_interpret` decides (interpret on a
+CPU backend, compiled elsewhere; ``REPRO_PALLAS_INTERPRET=0|1``
+overrides).  Interpreted, the kernel traces into the same XLA program as
+the staged engine, so it is a correctness path.  Compiled for a TPU only
+the tiled one-hot kernel lowers (``segsum="onehot"``, ``blk`` a multiple
+of 128 below ``F*W``, ``tick_window=1``); the other variants gather,
+scatter or draw random numbers inside the kernel, which Mosaic cannot
+lower, and raise a ``ValueError`` here before tracing reaches Mosaic.
 """
 from __future__ import annotations
 
-import os
-
-import jax
 import jax.numpy as jnp
 
 from ...core.netsim.params import (PackedTables, pack_route_tables,
@@ -26,6 +26,7 @@ from ...core.netsim.stages import (EngineState, instance_view, stage_marking,
                                    stage_metrics, stage_progress,
                                    stage_rate_control, stage_segments,
                                    stage_starts, static_pq_on)
+from .. import use_interpret
 from .kernel import TickOut, netsim_tick
 
 __all__ = ["use_interpret", "kernel_policy", "plan_tiling", "PackedTables",
@@ -33,19 +34,30 @@ __all__ = ["use_interpret", "kernel_policy", "plan_tiling", "PackedTables",
            "engine_tick_fused", "engine_window_fused"]
 
 
-def use_interpret() -> bool:
-    """Interpret-mode default: env override, else interpret on CPU."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    return jax.default_backend() == "cpu"
-
-
 def kernel_policy(cfg) -> str:
     """The in-kernel share policy for this config ("proportional"|"pq")."""
     if cfg.share_policy == "pq" or static_pq_on(cfg):
         return "pq"
     return "proportional"
+
+
+def _check_lowerable(FW: int, segsum: str, blk: int | None) -> None:
+    """Raise unless the compiled (Mosaic) kernel can lower this variant:
+    only the tiled one-hot kernel has a TPU lowering."""
+    if segsum != "onehot":
+        raise ValueError(
+            f"segsum={segsum!r} has no compiled TPU lowering (in-kernel "
+            "vector scatters and gathers); use segsum='onehot' with "
+            "blk=256, or run in interpret mode")
+    if blk is None:
+        raise ValueError(
+            f"the untiled segsum='onehot' kernel (blk unset or >= F*W={FW}) "
+            "has no compiled TPU lowering (whole-axis gathers); set "
+            "blk=256 (a multiple of 128 below F*W)")
+    if blk % 128:
+        raise ValueError(
+            f"blk={blk} must be a multiple of 128 (the TPU lane tile) for "
+            "the compiled tiled kernel; use blk=256")
 
 
 def fused_tick(ctx, cfg, starts, state, tick, *,
@@ -62,6 +74,10 @@ def fused_tick(ctx, cfg, starts, state, tick, *,
     if blk is None:
         blk = getattr(cfg, "blk", None)
     blk = plan_tiling(ctx.FW, blk, segsum, getattr(cfg, "tick_window", 1))
+    if interpret is None:
+        interpret = use_interpret()
+    if not interpret:
+        _check_lowerable(ctx.FW, segsum, blk)
     i32 = lambda v: jnp.asarray(v, jnp.int32)
     f32 = lambda v: jnp.asarray(v, jnp.float32)
     iscal = jnp.stack([i32(tick), i32(st.seed), i32(st.bg_period_ticks),
@@ -81,7 +97,7 @@ def fused_tick(ctx, cfg, starts, state, tick, *,
         dt=cfg.dt, mtu=cfg.mtu, per_step_ecmp=cfg.per_step_ecmp,
         policy=kernel_policy(cfg), segsum=segsum, blk=blk,
         tables=getattr(ctx, "tables", None),
-        interpret=use_interpret() if interpret is None else interpret)
+        interpret=interpret)
 
 
 def compose_tick(ctx, cfg, state: EngineState, tick, starts, out: TickOut):
@@ -134,7 +150,14 @@ def engine_window_fused(ctx, cfg, state: EngineState, base_tick, n: int):
     plan_tiling(ctx.FW, getattr(cfg, "blk", None),
                 getattr(cfg, "segsum", "scatter"),
                 getattr(cfg, "tick_window", 1))
+    interpret = use_interpret()
+    if not interpret:
+        raise ValueError(
+            f"tick_window={cfg.tick_window} runs the multi-tick window "
+            "kernel, which has no compiled TPU lowering (it replays the "
+            "cold stages' gathers, scatters and jax.random in-kernel); use "
+            "tick_window=1 with segsum='onehot', blk=256")
     return netsim_window(ctx, cfg, state, base_tick, n,
                          policy=kernel_policy(cfg),
                          segsum=getattr(cfg, "segsum", "scatter"),
-                         interpret=use_interpret())
+                         interpret=interpret)

@@ -29,10 +29,10 @@ the packed per-instance route/chunk/ECMP tables (`params.PackedTables`)
 their one initial DMA per *window*, not per tick.  With ``blk`` set the
 tiling normalizes away here (``params.plan_tiling`` returns ``None``
 for ``tick_window > 1``): windowing already amortizes the state traffic
-the tiling would stream.  The kernel is exercised in interpret mode on
-CPU; the cold stages it replays contain gathers/scatters that Mosaic
-cannot lower today, so the Mosaic-readiness CI gate covers the tiled
-single-tick kernel only.
+the tiling would stream.  The kernel runs in interpret mode only: the
+cold stages it replays gather, scatter and draw ``jax.random`` numbers
+in-kernel, none of which Mosaic lowers, so `ops.engine_window_fused`
+raises a ``ValueError`` when it would be compiled.
 
 The carried engine state is donated: the pallas call aliases each of
 the ``N_STATE`` state inputs to its same-shaped state output

@@ -20,6 +20,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import use_interpret
+
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, state_ref,
                 *, nc, Q):
@@ -61,9 +63,11 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, state_ref,
         fs_ref[0] = state_new.astype(fs_ref.dtype)
 
 
-def ssd_chunked(x, a, Bm, Cm, *, chunk: int, n_heads: int, interpret=True):
+def ssd_chunked(x, a, Bm, Cm, *, chunk: int, n_heads: int, interpret=None):
     """x: [BH, S, P]; a: [BH, S]; Bm/Cm: [B, S, N] (shared across heads).
     Returns (y [BH,S,P], final_state [BH,N,P])."""
+    if interpret is None:
+        interpret = use_interpret()
     BH, S, P = x.shape
     N = Bm.shape[-1]
     Q = chunk

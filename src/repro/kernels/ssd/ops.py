@@ -1,14 +1,10 @@
 """jit'd wrapper for the SSD kernel, model-side calling convention."""
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
 from .kernel import ssd_chunked
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def ssd(x, a, Bm, Cm, *, chunk: int = 128):
@@ -25,8 +21,7 @@ def ssd(x, a, Bm, Cm, *, chunk: int = 128):
     Sp = S + pad
     xf = x.transpose(0, 2, 1, 3).reshape(B * H, Sp, P)
     af = a.transpose(0, 2, 1).reshape(B * H, Sp)
-    y, fs = ssd_chunked(xf, af, Bm, Cm, chunk=chunk, n_heads=H,
-                        interpret=INTERPRET)
+    y, fs = ssd_chunked(xf, af, Bm, Cm, chunk=chunk, n_heads=H)
     y = y.reshape(B, H, Sp, P).transpose(0, 2, 1, 3)[:, :S]
     final = fs.reshape(B, H, N, P).transpose(0, 1, 3, 2)   # [B,H,P,N]
     return y, final

@@ -26,6 +26,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import use_interpret
+
 # 16-entry mantissa log2 LUT: log2(1 + i/16), the kind of table a switch ALU
 # indexes with the mantissa's top 4 bits.
 _LOG2_LUT = np.log2(1.0 + np.arange(16) / 16.0).astype(np.float32)
@@ -110,9 +112,11 @@ def _pipeline_kernel(lut_ref, steps_ref, psns_ref, lasts_ref, wins_ref, u_ref,
 
 def switch_pipeline(steps, psns, lasts, win_ends, uniforms, *,
                     k=0.01, tau=0.25, n_warmup=16, n_sample=32,
-                    alpha_max=64.0, exact=True, blk=256, interpret=True):
+                    alpha_max=64.0, exact=True, blk=256, interpret=None):
     """Process a packet batch through Alg. 1.  All inputs [P].
     Returns (marks i32, step_min i32, psn_rec f32, alpha f32) per packet."""
+    if interpret is None:
+        interpret = use_interpret()
     P = steps.shape[0]
     pad = (-P) % blk
     if pad:

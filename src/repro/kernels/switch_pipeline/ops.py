@@ -1,8 +1,4 @@
 """jit'd wrapper for the switch-pipeline kernel."""
 from __future__ import annotations
 
-import os
-
 from .kernel import switch_pipeline  # noqa: F401
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"

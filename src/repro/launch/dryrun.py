@@ -32,13 +32,6 @@ ICI_BW = 50e9                # bytes/s / link (~per direction)
 HBM_BYTES = 16 * 1024**3
 
 
-
-def _cost_dict(ca):
-    """compiled.cost_analysis() returns a dict on current jax, [dict] on 0.4.x."""
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
 def collective_bytes(hlo: str) -> dict:
     """Sum operand bytes of collective ops in compiled HLO, grouped by kind,
     with ring-cost wire-byte estimates per chip."""
@@ -142,7 +135,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             _, c2 = _measure(arch, shape_name, mesh, overrides, 2 * period)
 
             def costs(comp):
-                ca = _cost_dict(comp.cost_analysis())
+                ca = comp.cost_analysis() or {}
                 colls = collective_bytes(comp.as_text())
                 return (float(ca.get("flops", 0.0)),
                         float(ca.get("bytes accessed", 0.0)),
@@ -195,7 +188,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
     colls = collective_bytes(hlo)
     n_chips = mesh.size

@@ -1,8 +1,7 @@
 """Tests for the generalized staged netsim engine.
 
-* Golden equivalence: the variable-hop engine must reproduce the seed
-  2-tier/4-hop results bit-for-bit on the Table-1 scenario (constants below
-  were captured from the pre-refactor monolithic simulator).
+* Golden equivalence: the variable-hop engine must reproduce the Table-1
+  finish ticks bit-for-bit (constants in ``netsim_goldens``).
 * Unit tests for the stage functions and share policies.
 * Fat-tree link indexing / candidate-path correctness.
 * End-to-end runs of the new topologies and collectives through the
@@ -19,21 +18,8 @@ from repro.core.netsim.simulator import wl_arrays
 from repro.core.netsim import stages
 from repro.core.netsim.stages import (make_ctx, init_state, select_routes,
                                       seg_global, wire_step)
-
-# ---------------------------------------------------------------- golden
-# Captured from the pre-refactor engine (monolithic simulate_core, fixed
-# [F, 4] routes): Table-1 fabric, 4 rings of 8 over 32 hosts, 1 MB chunks,
-# 2 back-to-back passes, seed 3.
-GOLDEN_JOB = {"ecmp_base": 10757, "ecmp_sym": 7900,
-              "balanced_sym": 2239, "ecmp_pq": 10303}
-GOLDEN_FLOWS_ECMP_BASE = [
-    9296, 7344, 7659, 8375, 8795, 9180, 9359, 9439, 10450, 10648, 10728,
-    10601, 10268, 10348, 9887, 10228, 10658, 10757, 10754, 10205, 10011,
-    10053, 10007, 10383, 9050, 9050, 9009, 8801, 8734, 9119, 9081, 9107]
-GOLDEN_FLOWS_ECMP_SYM = [
-    7853, 7891, 7769, 7877, 7837, 7864, 7698, 7900, 7845, 7894, 7802, 7889,
-    7807, 7843, 7699, 7893, 7824, 7892, 7825, 7878, 7748, 7860, 7698, 7861,
-    7853, 7877, 7764, 7877, 7747, 7835, 7692, 7891]
+from netsim_goldens import (GOLDEN_FLOWS_ECMP_BASE, GOLDEN_FLOWS_ECMP_SYM,
+                            GOLDEN_JOB)
 
 
 def _table1():
@@ -45,7 +31,8 @@ def _table1():
 
 
 def test_golden_equivalence_table1():
-    """Refactor preserves the seed engine bit-for-bit (sym on and off)."""
+    """The engine reproduces the Table-1 goldens bit-for-bit (sym on and
+    off)."""
     topo, wl = _table1()
     cfg = SimParams(n_ticks=20_000, window=64)
     base = simulate(topo, wl, cfg, routing="ecmp", seed=3)

@@ -8,7 +8,8 @@ executor.
 * the SimParams facade: split/merge round-trip, legacy simulate_core
   call form, structural-mismatch rejection.
 * the drr share policy and its registry selection.
-* benchmark cache keying (overrides hash + schema invalidation).
+* benchmark cache keying (overrides hash + schema invalidation) and the
+  placement of the JAX compilation cache.
 """
 import jax
 import numpy as np
@@ -280,3 +281,26 @@ def test_cached_discards_old_schema(tmp_path, monkeypatch):
     assert out["v"] == "fresh"
     data = json.loads(path.read_text())
     assert data["__schema__"] == common.CACHE_SCHEMA
+
+
+@pytest.mark.parametrize("env_dir", [None, "placed-outside"])
+def test_compile_cache_placement(tmp_path, monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, wins and the program sets no
+    cache of its own; otherwise the cache sits at the fixed
+    <repo>/.jax_cache."""
+    from pathlib import Path
+
+    import benchmarks.common as common
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(Path(common.__file__).resolve().parents[1] / ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert common.enable_compile_cache() == want
+        set_dir = jax.config.jax_compilation_cache_dir
+        assert set_dir == (want if env_dir is None else before)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

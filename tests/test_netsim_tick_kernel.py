@@ -3,7 +3,7 @@
 The staged XLA engine is the golden reference: in interpret mode with
 ``segsum="scatter"`` the kernel must match it **bit-for-bit**, both
 per-output on single ticks and tick-for-tick through whole runs — the
-seed golden chain (Table-1 finish-tick constants) must hold unchanged
+Table-1 golden finish ticks (``netsim_goldens``) must hold unchanged
 under ``backend="pallas"``.
 """
 import warnings
@@ -21,11 +21,7 @@ from repro.core.netsim.stages import (engine_tick, engine_tick_xla,
                                       stage_starts)
 from repro.kernels.netsim_tick import (fused_outputs_ref, fused_tick,
                                        engine_tick_fused)
-
-# Same constants as tests/test_netsim_engine.py: captured from the seed
-# engine on the Table-1 scenario.  The pallas backend must reproduce them.
-GOLDEN_JOB = {"ecmp_base": 10757, "ecmp_sym": 7900,
-              "balanced_sym": 2239, "ecmp_pq": 10303}
+from netsim_goldens import GOLDEN_JOB   # the pallas backend must match these
 
 
 def _table1():
@@ -159,7 +155,7 @@ def test_unknown_backend_rejected():
 
 # --------------------------------------------------------- golden chain
 def test_golden_table1_pallas():
-    """Acceptance: the pallas backend reproduces the seed golden finish
+    """Acceptance: the pallas backend reproduces the golden finish
     ticks on Table 1 (ecmp, sym off/on) — the chain stays bit-for-bit."""
     topo, wl = _table1()
     cfg = SimParams(n_ticks=20_000, window=64, backend="pallas")

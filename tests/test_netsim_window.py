@@ -24,9 +24,7 @@ from repro.core.netsim import (SimController, SimParams, WorkloadBuilder,
                                simulate, simulate_grid)
 from repro.core.netsim.simulator import I32MAX, _resolve_routing, wl_arrays
 
-# Table-1 goldens (captured from the seed engine; see test_netsim_engine).
-GOLDEN_JOB = {"ecmp_base": 10757, "ecmp_sym": 7900,
-              "balanced_sym": 2239, "ecmp_pq": 10303}
+from netsim_goldens import GOLDEN_JOB
 
 
 def _table1():
