@@ -33,6 +33,7 @@ from typing import Mapping, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import metrics
 from .params import RuntimeKnobs, SimParams, SimState, SimStructure
@@ -132,6 +133,7 @@ class SimController:
                 f"window_ticks must be a positive multiple of "
                 f"record_every={R}, got {window_ticks}")
         self.window_ticks = w
+        self.steps = 0                # step() calls, the span's ``step`` arg
         self._seed = seed
         self.state: SimState = init_state(
             self.st, self.wla, struct, jax.random.PRNGKey(seed))
@@ -139,19 +141,28 @@ class SimController:
     # ------------------------------------------------------------- control
     def step(self, action: Mapping[str, float] | None = None,
              n_ticks: int | None = None) -> tuple[SimState, StepObs]:
-        """Apply ``action`` (optional knob retunes), run one window."""
-        if action:
-            self.knobs = apply_action(self.knobs, action)
-        self.state, samples = run_window(
-            self.st, self.wla, self.struct, self.knobs, self.state,
-            self.window_ticks if n_ticks is None else n_ticks)
-        jf = np.asarray(self.state.engine.job_finish)
-        finished = jf != I32MAX
-        tick = int(self.state.tick)
-        obs = StepObs(
-            tick=tick, t=tick * self.struct.dt,
-            stats=metrics.window_summary(samples), samples=samples,
-            job_finished=finished, done=bool(finished.all()))
+        """Apply ``action`` (optional knob retunes), run one window.
+
+        Profiler spans: ``netsim.step`` (args ``step``, the controller's
+        step count, and ``ticks``) around ``netsim.step.action``, the
+        ``netsim.window.*`` spans of :func:`run_window`, and
+        ``netsim.step.observe`` (the readbacks and the summary)."""
+        n = self.window_ticks if n_ticks is None else n_ticks
+        with TraceAnnotation("netsim.step", step=self.steps, ticks=n):
+            self.steps += 1
+            if action:
+                with TraceAnnotation("netsim.step.action"):
+                    self.knobs = apply_action(self.knobs, action)
+            self.state, samples = run_window(
+                self.st, self.wla, self.struct, self.knobs, self.state, n)
+            with TraceAnnotation("netsim.step.observe"):
+                jf = np.asarray(self.state.engine.job_finish)
+                finished = jf != I32MAX
+                tick = int(self.state.tick)
+                obs = StepObs(
+                    tick=tick, t=tick * self.struct.dt,
+                    stats=metrics.window_summary(samples), samples=samples,
+                    job_finished=finished, done=bool(finished.all()))
         return self.state, obs
 
     def run(self, n_windows: int,

@@ -79,6 +79,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import compat
 
@@ -561,6 +562,10 @@ def run_window(st: Static, wl: WLArrays, struct: SimStructure,
 
     Returns ``(state', samples)`` where ``samples`` is a
     :class:`WindowSamples` covering this window's record periods.
+
+    Profiler spans: ``netsim.window.batch`` (the leading lane axis put
+    on), ``netsim.window.launch`` (the jitted call), and
+    ``netsim.window.unbatch`` (the lane axis taken off).
     """
     _check_pq_conflict(struct, knobs.pq_on)
     if struct.backend not in BACKENDS:
@@ -576,13 +581,14 @@ def run_window(st: Static, wl: WLArrays, struct: SimStructure,
         raise ValueError(
             f"n_ticks must be a positive multiple of record_every={R} "
             f"(samples are taken on the record grid), got {n_ticks}")
-    sim, samples = _window_core(
-        jax.tree.map(lambda x: x[None], st), wl,
-        jax.tree.map(lambda x: x[None], knobs),
-        jax.tree.map(lambda x: x[None], state),
-        struct=struct, n_ticks=n_ticks)
-    return (jax.tree.map(lambda x: x[0], sim),
-            WindowSamples(*(x[0] for x in samples)))
+    with TraceAnnotation("netsim.window.batch"):
+        sts, kns, sims = jax.tree.map(lambda x: x[None], (st, knobs, state))
+    with TraceAnnotation("netsim.window.launch"):
+        sim, samples = _window_core(sts, wl, kns, sims, struct=struct,
+                                    n_ticks=n_ticks)
+    with TraceAnnotation("netsim.window.unbatch"):
+        return (jax.tree.map(lambda x: x[0], sim),
+                WindowSamples(*(x[0] for x in samples)))
 
 
 # ------------------------------------------------------------ entry points
@@ -666,6 +672,11 @@ def simulate_grid(topo: Topology, wl: Workload, struct: SimStructure,
 
     Returns a :class:`SimResult` whose arrays carry leading ``[K, S]``
     axes (knob point x seed).
+
+    Profiler spans: ``netsim.grid`` (args ``lanes`` = K*S and ``ticks``)
+    around ``netsim.grid.statics`` (the stacked per-seed arrays),
+    ``netsim.grid.launch`` (the jitted calls, which return before the
+    chip finishes) and, with more than one chunk, ``netsim.grid.concat``.
     """
     if (isinstance(knobs_grid, (list, tuple))
             and not isinstance(knobs_grid, RuntimeKnobs)):
@@ -687,31 +698,39 @@ def simulate_grid(topo: Topology, wl: Workload, struct: SimStructure,
     _check_pq_conflict(struct, knobs_grid.pq_on)
     mesh = resolve_grid_mesh(devices, mesh)
     struct, mode = _resolve_routing(struct, routing)
-    stacked, keys = _stacked_statics(topo, wl, mode, seeds, struct, **bg)
-    wla = wl_arrays(wl, struct.dt)
-
     K = int(jax.tree.leaves(knobs_grid)[0].shape[0])
-    D = 1 if mesh is None else int(mesh.devices.size)
-    # chunk_knobs bounds the knob points resident PER DEVICE, so a
-    # D-device dispatch covers chunk_knobs * D points at a time.
-    per_dev = K if chunk_knobs is None else max(1, min(int(chunk_knobs), K))
-    chunk = min(K, per_dev * D)
-    pad = (-K) % chunk
-    if pad:
-        # repeat the final point so the last partial chunk has the same
-        # shape as the others (one trace); its padded rows are sliced off
-        # the concatenated result below, never observed by callers.
-        knobs_grid = jax.tree.map(
-            lambda x: jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)]),
-            knobs_grid)
-    outs = []
-    for i in range(0, K + pad, chunk):
-        kn = jax.tree.map(lambda x: x[i:i + chunk], knobs_grid)
-        if mesh is None:
-            outs.append(_grid_core(stacked, wla, struct, kn, keys))
-        else:
-            outs.append(_sharded_core(stacked, wla, kn, keys,
-                                      struct=struct, mesh=mesh))
-    if len(outs) == 1:
-        return outs[0]
-    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0)[:K], *outs)
+    with TraceAnnotation("netsim.grid", lanes=K * len(seeds),
+                         ticks=struct.n_ticks):
+        with TraceAnnotation("netsim.grid.statics"):
+            stacked, keys = _stacked_statics(topo, wl, mode, seeds, struct,
+                                             **bg)
+            wla = wl_arrays(wl, struct.dt)
+
+        D = 1 if mesh is None else int(mesh.devices.size)
+        # chunk_knobs bounds the knob points resident PER DEVICE, so a
+        # D-device dispatch covers chunk_knobs * D points at a time.
+        per_dev = K if chunk_knobs is None else \
+            max(1, min(int(chunk_knobs), K))
+        chunk = min(K, per_dev * D)
+        pad = (-K) % chunk
+        if pad:
+            # repeat the final point so the last partial chunk has the same
+            # shape as the others (one trace); its padded rows are sliced
+            # off the concatenated result below, never observed by callers.
+            knobs_grid = jax.tree.map(
+                lambda x: jnp.concatenate(
+                    [x, jnp.repeat(x[-1:], pad, axis=0)]), knobs_grid)
+        outs = []
+        with TraceAnnotation("netsim.grid.launch"):
+            for i in range(0, K + pad, chunk):
+                kn = jax.tree.map(lambda x: x[i:i + chunk], knobs_grid)
+                if mesh is None:
+                    outs.append(_grid_core(stacked, wla, struct, kn, keys))
+                else:
+                    outs.append(_sharded_core(stacked, wla, kn, keys,
+                                              struct=struct, mesh=mesh))
+        if len(outs) == 1:
+            return outs[0]
+        with TraceAnnotation("netsim.grid.concat"):
+            return jax.tree.map(
+                lambda *xs: jnp.concatenate(xs, axis=0)[:K], *outs)

@@ -31,9 +31,18 @@ and the merged :class:`~repro.core.netsim.params.EngineParams`, whose knob
 fields (RED/CC/Symphony constants, ``sym_on``/``pq_on`` gates) are traced
 arrays — so the same stage code serves single runs and vmapped knob grids
 without retracing per parameter point.
+
+Each stage runs under a ``jax.named_scope`` of its own (``netsim.starts``,
+``netsim.instance_view``, ``netsim.share``, ``netsim.queues``,
+``netsim.marking``, ``netsim.progress``, ``netsim.symphony``,
+``netsim.rate_control``, ``netsim.segments``, ``netsim.metrics``), so the
+ops it lowers to carry that name in their HLO ``op_name`` metadata and a
+profiler trace attributes device time to stages.  The scope is trace-time
+metadata only: the computed values and the compile count are unchanged.
 """
 from __future__ import annotations
 
+import functools
 import warnings
 from dataclasses import dataclass
 from typing import Any, Callable, NamedTuple
@@ -54,6 +63,17 @@ I32MAX = np.iinfo(np.int32).max
 # bodies, which cannot capture device-array constants (the multi-tick
 # window kernel replays the stages per tick).
 BIG = 2**30
+
+
+def _scoped(name: str):
+    """Run the decorated stage under ``jax.named_scope(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 class WLArrays(NamedTuple):
@@ -194,6 +214,7 @@ class Starts(NamedTuple):
     can: jax.Array
 
 
+@_scoped("netsim.starts")
 def stage_starts(ctx: EngineCtx, state: EngineState, tick) -> Starts:
     """Gate new step-sends on segment barrier + ring data dependency + slot
     availability, and initialize the window slots of the started steps."""
@@ -310,6 +331,7 @@ def select_routes(ctx: EngineCtx, istep, per_step_ecmp: bool) -> jax.Array:
     return st.path_table[ctx.inst_flow, choice]
 
 
+@_scoped("netsim.instance_view")
 def instance_view(ctx: EngineCtx, starts: Starts, state: EngineState,
                   mtu: float, per_step_ecmp: bool,
                   iroute: jax.Array | None = None) -> InstView:
@@ -436,6 +458,7 @@ SHARE_POLICIES: dict[str, Callable[..., ShareResult]] = {
 
 
 # --------------------------------------------------------- 4. queues + RED
+@_scoped("netsim.queues")
 def stage_queues(ctx: EngineCtx, cfg, q_prev, offered):
     """Integrate per-link queues and derive the RED marking profile."""
     q = jnp.maximum(q_prev + (offered - ctx.st.cap) * cfg.dt, 0.0)
@@ -446,6 +469,7 @@ def stage_queues(ctx: EngineCtx, cfg, q_prev, offered):
 
 
 # ------------------------------------------------------------- 5. marking
+@_scoped("netsim.marking")
 def stage_marking(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
                   p_red, eff, lam, tick):
     """Combine RED with Symphony's selective marking along each path into
@@ -472,6 +496,7 @@ def stage_marking(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
 
 
 # ------------------------------------------------------------ 6. progress
+@_scoped("netsim.progress")
 def stage_progress(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
                    step_of, eff, tick):
     """Advance per-instance bytes, retire completed steps in order, record
@@ -493,6 +518,7 @@ def stage_progress(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
 
 
 # ------------------------------------------------------ 7. Symphony state
+@_scoped("netsim.symphony")
 def stage_symphony(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
                    sm, pkts, newly_done, eff, tick):
     """Per-(domain, job) state blocks: traffic stats, optimistic step-min
@@ -535,6 +561,7 @@ def stage_symphony(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
 
 
 # -------------------------------------------------------- 8. rate control
+@_scoped("netsim.rate_control")
 def stage_rate_control(ctx: EngineCtx, cfg, starts: Starts, lam, key, tick):
     """DCQCN-style epoch update driven by the accumulated mark probability."""
     F, W = ctx.F, ctx.W
@@ -571,6 +598,7 @@ def stage_rate_control(ctx: EngineCtx, cfg, starts: Starts, lam, key, tick):
 
 
 # ----------------------------------------------------- 9. segments / jobs
+@_scoped("netsim.segments")
 def stage_segments(ctx: EngineCtx, state: EngineState, done_upto, tick):
     """Advance the job-wide segment barrier and record job finish ticks."""
     wl, J = ctx.wl, ctx.J
@@ -602,6 +630,7 @@ def stage_segments(ctx: EngineCtx, state: EngineState, done_upto, tick):
 
 
 # ------------------------------------------------------------ 10. metrics
+@_scoped("netsim.metrics")
 def stage_metrics(ctx: EngineCtx, inst: InstView, done_upto, eff, q, s_alpha):
     """The sampled observables of one tick."""
     J, L = ctx.J, ctx.L
@@ -643,6 +672,7 @@ def resolve_share_policy(cfg) -> Callable[..., ShareResult]:
             f"unknown share policy {name!r}; have {sorted(SHARE_POLICIES)}")
 
 
+@_scoped("netsim.share")
 def stage_share(ctx: EngineCtx, cfg, inst: InstView, tick) -> ShareResult:
     """Bandwidth sharing with the runtime ``pq_on`` override.
 

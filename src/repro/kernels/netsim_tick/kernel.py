@@ -788,6 +788,7 @@ def _tiled_tick(operands, tables, *, FW, H, L1, DJ, J, SEG, blk, dt, mtu,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="netsim_tick",
     )(nvalid, *ins)
     iroute, eff = outs[0][:, :FW].T, outs[1][0, :FW]
     links = [o[:L1, 0] for o in outs[2:5]]
@@ -879,6 +880,6 @@ def netsim_tick(step_of, sent, rate, done_upto, q_prev,
         _tick_kernel, H=H, SEG=int(chunk_sched.shape[-1]), dt=float(dt),
         mtu=float(mtu), per_step_ecmp=bool(per_step_ecmp), policy=policy,
         segsum=segsum)
-    outs = pl.pallas_call(kernel, out_shape=out_shape,
-                          interpret=interpret)(*operands)
+    outs = pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret,
+                          name="netsim_tick")(*operands)
     return TickOut(*outs)

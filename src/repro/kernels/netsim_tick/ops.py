@@ -18,6 +18,7 @@ lower, and raise a ``ValueError`` here before tracing reaches Mosaic.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ...core.netsim.params import (PackedTables, pack_route_tables,
@@ -67,7 +68,9 @@ def fused_tick(ctx, cfg, starts, state, tick, *,
     """Marshal engine state into the kernel's flat operands and run it.
 
     ``segsum`` / ``blk`` default to the config's static fields (both
-    overridable for direct kernel tests)."""
+    overridable for direct kernel tests).  The XLA ops around the kernel
+    (scalar packing, the tiled kernel's operand layout, the slicing of
+    its outputs) run under the named scope ``netsim.kernel_operands``."""
     st = ctx.st
     if segsum is None:
         segsum = getattr(cfg, "segsum", "scatter")
@@ -78,26 +81,28 @@ def fused_tick(ctx, cfg, starts, state, tick, *,
         interpret = use_interpret()
     if not interpret:
         _check_lowerable(ctx.FW, segsum, blk)
-    i32 = lambda v: jnp.asarray(v, jnp.int32)
-    f32 = lambda v: jnp.asarray(v, jnp.float32)
-    iscal = jnp.stack([i32(tick), i32(st.seed), i32(st.bg_period_ticks),
-                       i32(cfg.sym_win_ticks), i32(cfg.pq_on)])
-    fscal = jnp.stack([f32(st.bg_duty), f32(cfg.red_kmin), f32(cfg.red_kmax),
-                       f32(cfg.red_pmax), f32(cfg.sym.tau),
-                       f32(cfg.sym.n_sample), f32(cfg.sym.alpha_max)])
-    return netsim_tick(
-        starts.step_of.reshape(ctx.FW), starts.sent.reshape(ctx.FW),
-        starts.rate.reshape(ctx.FW), state.done_upto, state.q,
-        state.s_stepmin, state.s_psnwin, state.s_alpha,
-        state.s_cnt, state.s_cntop,
-        st.routes, st.path_table, st.n_paths, st.cap, st.link_dom,
-        st.bg_base, st.bg_amp,
-        ctx.inst_job, ctx.inst_flow, ctx.sps_i, ctx.phase_i, ctx.nph_i,
-        ctx.off_i, ctx.wl.chunk_sched, iscal, fscal,
-        dt=cfg.dt, mtu=cfg.mtu, per_step_ecmp=cfg.per_step_ecmp,
-        policy=kernel_policy(cfg), segsum=segsum, blk=blk,
-        tables=getattr(ctx, "tables", None),
-        interpret=interpret)
+    with jax.named_scope("netsim.kernel_operands"):
+        i32 = lambda v: jnp.asarray(v, jnp.int32)
+        f32 = lambda v: jnp.asarray(v, jnp.float32)
+        iscal = jnp.stack([i32(tick), i32(st.seed), i32(st.bg_period_ticks),
+                           i32(cfg.sym_win_ticks), i32(cfg.pq_on)])
+        fscal = jnp.stack([f32(st.bg_duty), f32(cfg.red_kmin),
+                           f32(cfg.red_kmax), f32(cfg.red_pmax),
+                           f32(cfg.sym.tau), f32(cfg.sym.n_sample),
+                           f32(cfg.sym.alpha_max)])
+        return netsim_tick(
+            starts.step_of.reshape(ctx.FW), starts.sent.reshape(ctx.FW),
+            starts.rate.reshape(ctx.FW), state.done_upto, state.q,
+            state.s_stepmin, state.s_psnwin, state.s_alpha,
+            state.s_cnt, state.s_cntop,
+            st.routes, st.path_table, st.n_paths, st.cap, st.link_dom,
+            st.bg_base, st.bg_amp,
+            ctx.inst_job, ctx.inst_flow, ctx.sps_i, ctx.phase_i, ctx.nph_i,
+            ctx.off_i, ctx.wl.chunk_sched, iscal, fscal,
+            dt=cfg.dt, mtu=cfg.mtu, per_step_ecmp=cfg.per_step_ecmp,
+            policy=kernel_policy(cfg), segsum=segsum, blk=blk,
+            tables=getattr(ctx, "tables", None),
+            interpret=interpret)
 
 
 def compose_tick(ctx, cfg, state: EngineState, tick, starts, out: TickOut):

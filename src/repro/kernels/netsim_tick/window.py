@@ -186,6 +186,7 @@ def netsim_window(ctx, cfg, state: EngineState, base_tick, n: int, *,
         # instead of copying all N_STATE arrays once per window.
         input_output_aliases={i: i for i in range(N_STATE)},
         interpret=interpret,
+        name="netsim_window",
     )(*operands)
     new_state = EngineState(*outs[:N_STATE])
     sample = (outs[N_STATE], outs[N_STATE + 1], outs[N_STATE + 2],
