@@ -259,25 +259,64 @@ class PackedTables(NamedTuple):
     chunk: jax.Array      # [FW, SEG]  per-instance segment chunk sizes
 
 
-def pack_route_tables(st, wl, window: int) -> PackedTables:
+class PathIndex(NamedTuple):
+    """Flow-granular route index tables, ``[F, P, H]`` each: plane ``p`` of
+    flow ``f`` is its ``p``-th ECMP candidate path (``P = 1``: the static
+    route).  An instance's route is fixed by its flow and its candidate, so
+    a per-link or per-(domain, job) value is gathered here once per (flow,
+    candidate, hop) and picked per instance (`stages.InstView.on_path`)."""
+    links: jax.Array   # link ids
+    dom: jax.Array     # Symphony domain of each hop
+    dj: jax.Array      # (domain, job) row of each hop
+
+
+class RoutePaths(NamedTuple):
+    """The two routings a tick may take, as :class:`PathIndex` tables."""
+    cand: PathIndex    # [F, P, H] ECMP candidates (per-step ECMP)
+    static: PathIndex  # [F, 1, H] static per-flow routes
+
+
+def route_paths(st, wl) -> RoutePaths:
+    """Index the candidate and static routes once: links, their Symphony
+    domains, and the (domain, job) rows ``dom * J + job``.
+
+    ``st`` needs ``routes``/``path_table``/``link_dom``; ``wl`` needs
+    ``job``/``n_phases`` (duck-typed like :func:`pack_route_tables`).
+    """
+    J = int(wl.n_phases.shape[0])
+
+    def index(links):
+        dom = st.link_dom[links]
+        return PathIndex(links=links, dom=dom,
+                         dj=dom * J + wl.job[:, None, None])
+
+    return RoutePaths(cand=index(st.path_table),
+                      static=index(st.routes[:, None, :]))
+
+
+def pack_route_tables(st, wl, window: int,
+                      paths: RoutePaths | None = None) -> PackedTables:
     """Expand the per-flow/per-job tables to the ``[FW]`` instance axis.
 
     ``st`` needs ``routes``/``path_table``/``n_paths``/``link_dom``;
-    ``wl`` needs ``job``/``chunk_sched`` (duck-typed: `simulator.Static`
-    and `stages.WLArrays`).  The window expansion is ``jnp.repeat(x, W,
-    axis=0)`` — row ``f*W + w`` holds flow ``f``'s table, matching the
-    ``inst_flow``/``inst_job`` layout of `stages.make_ctx`.
+    ``wl`` needs ``job``/``n_phases``/``chunk_sched`` (duck-typed:
+    `simulator.Static` and `stages.WLArrays`).  The route rows repeat
+    ``paths`` (:func:`route_paths` when None).  The window expansion is
+    ``jnp.repeat(x, W, axis=0)`` — row ``f*W + w`` holds flow ``f``'s
+    table, matching the ``inst_flow``/``inst_job`` layout of
+    `stages.make_ctx`.
     """
     W = int(window)
+    cand, static = route_paths(st, wl) if paths is None else paths
 
     def per_inst(x):
         return jnp.repeat(x, W, axis=0)
 
     return PackedTables(
-        routes=per_inst(st.routes),
-        route_dom=per_inst(st.link_dom[st.routes]),
-        cand=per_inst(st.path_table),
-        cand_dom=per_inst(st.link_dom[st.path_table]),
+        routes=per_inst(static.links[:, 0]),
+        route_dom=per_inst(static.dom[:, 0]),
+        cand=per_inst(cand.links),
+        cand_dom=per_inst(cand.dom),
         n_paths=per_inst(st.n_paths),
         chunk=per_inst(wl.chunk_sched[wl.job]),
     )

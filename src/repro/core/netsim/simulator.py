@@ -537,8 +537,17 @@ def init_state(st: Static, wl: WLArrays, struct: SimStructure,
             f"have {sorted(SHARE_POLICIES)}")
     if not isinstance(key, jax.Array):
         key = jax.random.PRNGKey(int(key))
-    ctx = make_ctx(st, wl, struct.window)
-    return SimState(tick=jnp.int32(0), engine=engine_init_state(ctx, key))
+    return SimState(tick=jnp.int32(0),
+                    engine=_engine_init(st, wl, struct.window, key))
+
+
+@functools.partial(jax.jit, static_argnames=("window",))
+def _engine_init(st: Static, wl: WLArrays, window: int,
+                 key: jax.Array) -> EngineState:
+    """The tick-0 engine state as one compiled program: run eagerly, the
+    context's route tables and the state's fills would each dispatch (and,
+    the first time, compile) an op of their own."""
+    return engine_init_state(make_ctx(st, wl, window), key)
 
 
 def run_window(st: Static, wl: WLArrays, struct: SimStructure,

@@ -39,6 +39,13 @@ Each stage runs under a ``jax.named_scope`` of its own (``netsim.starts``,
 ops it lowers to carry that name in their HLO ``op_name`` metadata and a
 profiler trace attributes device time to stages.  The scope is trace-time
 metadata only: the computed values and the compile count are unchanged.
+
+A per-hop lookup (a per-link or per-(domain, job) value at every hop of
+every instance's path) goes through :meth:`InstView.on_path`: it gathers
+once per (flow, ECMP candidate, hop) and picks each instance's candidate
+with exact selects, since an instance's route is fixed by its flow and
+its candidate.  The scatters onto links and (domain, job) rows stay per
+instance, so no sum changes order.
 """
 from __future__ import annotations
 
@@ -52,7 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..symphony import marking_probability
-from .params import PackedTables, pack_route_tables
+from .params import (PackedTables, PathIndex, RoutePaths,
+                     pack_route_tables, route_paths)
 
 # Wire-step encoding: global segment index * WIRE_SEG + step-within-segment.
 # Monotone across segments; comparable across flows inside a segment.
@@ -121,7 +129,9 @@ class EngineCtx:
     inst_job: jax.Array      # [FW]
     inst_flow: jax.Array     # [FW]
     sps_i: jax.Array; phase_i: jax.Array; nph_i: jax.Array; off_i: jax.Array
-    iroute_static: jax.Array  # [FW, H]
+    # flow-granular route tables (params.RoutePaths), built once per
+    # dispatch outside the scan: every per-hop lookup goes through them
+    paths: RoutePaths
     # per-instance dense route/chunk/ECMP tables (params.PackedTables):
     # the gather-free tiled kernel streams these instead of gathering.
     tables: PackedTables | None = None
@@ -129,6 +139,11 @@ class EngineCtx:
     @property
     def FW(self) -> int:
         return self.F * self.W
+
+    def per_inst(self, x: jax.Array) -> jax.Array:
+        """Per-flow ``[F]`` -> per-instance ``[FW]`` (row ``f*W + w`` is
+        flow ``f``): a broadcast, not a gather."""
+        return jnp.repeat(x, self.W)
 
     def chunk_of(self, job_ids, seg):
         max_seg = int(self.wl.chunk_sched.shape[1])
@@ -146,6 +161,7 @@ def make_ctx(st, wl: WLArrays, window: int,
     FW = F * W
     nph_f = wl.n_phases[wl.job]
     fidx = jnp.arange(F)
+    paths = route_paths(st, wl)
     return EngineCtx(
         st=st, wl=wl, F=F, J=J, W=W, L=L, H=H, D=D,
         fidx=fidx, nph_f=nph_f,
@@ -156,10 +172,9 @@ def make_ctx(st, wl: WLArrays, window: int,
         phase_i=jnp.broadcast_to(wl.phase[:, None], (F, W)).reshape(FW),
         nph_i=jnp.broadcast_to(nph_f[:, None], (F, W)).reshape(FW),
         off_i=jnp.broadcast_to(wl.step_offset[:, None], (F, W)).reshape(FW),
-        iroute_static=jnp.broadcast_to(
-            st.routes[:, None, :], (F, W, st.routes.shape[-1])
-        ).reshape(FW, st.routes.shape[-1]),
-        tables=pack_route_tables(st, wl, W) if tables is None else tables,
+        paths=paths,
+        tables=pack_route_tables(st, wl, W, paths) if tables is None
+        else tables,
     )
 
 
@@ -277,6 +292,23 @@ def link_scatter_sum(flat_links: jax.Array, vals: jax.Array, H: int,
     return jnp.zeros(n_rows).at[flat_links].add(per_hop(vals, H))
 
 
+def pick_plane(table: jax.Array, choice: jax.Array | None,
+               W: int) -> jax.Array:
+    """Per-flow ``[F, P, H]`` table -> per-instance ``[F*W, H]``: row
+    ``f*W + w`` takes plane ``choice[f*W + w]`` of flow ``f`` (``choice``
+    None: the one plane of a ``P = 1`` table).  A chain of selects, so the
+    pick is exact for ints and floats alike; ``choice`` is always below
+    the flow's candidate count."""
+    F, P, H = table.shape
+    plane = functools.partial(jax.lax.index_in_dim, table, axis=1)
+    acc = plane(P - 1)                      # [F, 1, H]
+    if choice is not None:
+        c = choice.reshape(F, W, 1)
+        for p in range(P - 2, -1, -1):
+            acc = jnp.where(c == p, plane(p), acc)
+    return jnp.broadcast_to(acc, (F, W, H)).reshape(F * W, H)
+
+
 class InstView(NamedTuple):
     """Flattened [FW] per-instance arrays for this tick.
 
@@ -284,7 +316,8 @@ class InstView(NamedTuple):
     scatter setup are precomputed once here and consumed through
     :meth:`per_hop` / :meth:`link_sum` / :meth:`path_min`, so every share
     policy — and the fused ``netsim_tick`` kernel — shares one index set
-    instead of rebuilding it per policy.
+    instead of rebuilding it per policy.  :meth:`on_path` reads a per-link
+    or per-(domain, job) array at every hop.
     """
     istep: jax.Array; isent: jax.Array; irate: jax.Array
     iseg: jax.Array; ichunk: jax.Array; iwire: jax.Array; ipsn: jax.Array
@@ -295,6 +328,10 @@ class InstView(NamedTuple):
     idom: jax.Array          # [FW, H] Symphony domain per hop
     dj: jax.Array            # [FW, H] (domain, job) row ids
     djf: jax.Array           # [FW*H]
+    # The flow-granular route tables of this tick's routing and each
+    # instance's plane in them (choice None: the static routes, P = 1).
+    paths: PathIndex
+    choice: jax.Array | None  # [FW]
 
     @property
     def H(self) -> int:
@@ -308,57 +345,83 @@ class InstView(NamedTuple):
         """Scatter-add per-instance ``vals`` onto the [L+1] link axis."""
         return link_scatter_sum(self.flat_links, vals, self.H, ctx.L + 1)
 
+    def on_path(self, x: jax.Array, *, dj: bool = False) -> jax.Array:
+        """``x`` at each instance's hops, ``[FW, H]``: ``x`` is a per-link
+        ``[L+1]`` array, or with ``dj`` a per-(domain, job) ``[DJ]`` one.
+        Gathered once per (flow, candidate, hop), picked per instance."""
+        table = self.paths.dj if dj else self.paths.links
+        return pick_plane(x[table], self.choice,
+                          self.istep.shape[0] // table.shape[0])
+
     def path_min(self, per_link: jax.Array) -> jax.Array:
         """Worst per-hop value along each instance's path: [L+1] -> [FW]."""
-        return per_link[self.iroute].min(axis=1)
+        return self.on_path(per_link).min(axis=1)
 
 
-def select_routes(ctx: EngineCtx, istep, per_step_ecmp: bool) -> jax.Array:
-    """Per-instance routes.  With per-step ECMP the step index is part of the
-    5-tuple (paper §4.7: it lives in the UDP sport), so each step re-rolls
-    its hash over the flow's candidate-path table; otherwise routes are the
-    static per-flow paths."""
-    if not per_step_ecmp:
-        return ctx.iroute_static
+def route_choice(ctx: EngineCtx, istep) -> jax.Array:
+    """Each instance's ECMP candidate index ``[FW]``.  The step index is
+    part of the 5-tuple (paper §4.7: it lives in the UDP sport), so each
+    step re-rolls its hash over the flow's candidate paths.  Elementwise."""
     st = ctx.st
     h = (ctx.inst_flow.astype(jnp.uint32) * jnp.uint32(2654435761)
          + jnp.maximum(istep, 0).astype(jnp.uint32) * jnp.uint32(40503)
          + (st.seed.astype(jnp.uint32) + 1) * jnp.uint32(2246822519))
     h = (h ^ (h >> 13)) * jnp.uint32(2654435761)
     h = h ^ (h >> 16)
-    n_paths = st.n_paths[ctx.inst_flow].astype(jnp.uint32)
-    choice = (h % n_paths).astype(jnp.int32)
-    return st.path_table[ctx.inst_flow, choice]
+    n_paths = ctx.per_inst(st.n_paths).astype(jnp.uint32)
+    return (h % n_paths).astype(jnp.int32)
+
+
+def select_routes(ctx: EngineCtx, istep, per_step_ecmp: bool) -> jax.Array:
+    """Per-instance routes gathered per instance: with per-step ECMP the
+    hashed candidate (:func:`route_choice`), otherwise the static per-flow
+    paths.  The reference that :func:`instance_view`'s plane pick and the
+    kernel's route selection both equal; the tick itself does not call it."""
+    if not per_step_ecmp:
+        return ctx.st.routes[ctx.inst_flow]
+    return ctx.st.path_table[ctx.inst_flow, route_choice(ctx, istep)]
+
+
+def _pick_col(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[arange(N), idx]`` for an ``[N, C]`` table of non-negative
+    values: one entry per row survives the mask, so the sum is exact."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, table.shape, 1) == idx[:, None]
+    return jnp.where(hit, table, 0).sum(axis=1)
 
 
 @_scoped("netsim.instance_view")
 def instance_view(ctx: EngineCtx, starts: Starts, state: EngineState,
-                  mtu: float, per_step_ecmp: bool,
-                  iroute: jax.Array | None = None) -> InstView:
-    """Assemble the per-instance view.  ``iroute`` may be precomputed (the
-    fused kernel selects routes on-chip and hands them back) — otherwise
-    it is derived here via :func:`select_routes`."""
-    st, J = ctx.st, ctx.J
+                  mtu: float, per_step_ecmp: bool) -> InstView:
+    """Assemble the per-instance view.  Each instance's route, domains and
+    (domain, job) rows are picked from the flow-granular tables
+    (:class:`PathIndex`) by its ECMP candidate, and every per-hop lookup
+    goes through them (:meth:`InstView.on_path`): F*P*H indices gathered
+    instead of F*W*H.  On the kernel path this recomputes the candidate the
+    kernel hashed, so one choice decides both the scatters and the
+    lookups."""
+    J, W = ctx.J, ctx.W
     istep = starts.step_of.reshape(ctx.FW)
     isent = starts.sent.reshape(ctx.FW)
     irate = starts.rate.reshape(ctx.FW)
     iseg = seg_global(istep, ctx.sps_i, ctx.phase_i, ctx.nph_i)
-    ichunk = ctx.chunk_of(ctx.inst_job, iseg)
+    SEG = int(ctx.wl.chunk_sched.shape[1])
+    ichunk = _pick_col(ctx.tables.chunk, jnp.clip(iseg, 0, SEG - 1))
     iwire = wire_step(istep, ctx.sps_i, ctx.phase_i, ctx.nph_i) + ctx.off_i
     occupied = istep >= 0
-    retired = occupied & (istep < state.done_upto[ctx.inst_flow])
+    retired = occupied & (istep < ctx.per_inst(state.done_upto))
     complete = occupied & (isent >= ichunk)
     active = occupied & ~complete & ~retired
-    if iroute is None:
-        iroute = select_routes(ctx, istep, per_step_ecmp)
-    idom = st.link_dom[iroute]
+    paths = ctx.paths.cand if per_step_ecmp else ctx.paths.static
+    choice = route_choice(ctx, istep) if per_step_ecmp else None
+    iroute = pick_plane(paths.links, choice, W)
+    idom = pick_plane(paths.dom, choice, W)
     dj = idom * J + ctx.inst_job[:, None]
     return InstView(
         istep=istep, isent=isent, irate=irate, iseg=iseg, ichunk=ichunk,
         iwire=iwire, ipsn=isent / mtu,
         occupied=occupied, retired=retired, complete=complete, active=active,
         iroute=iroute, flat_links=iroute.reshape(-1),
-        idom=idom, dj=dj, djf=dj.reshape(-1),
+        idom=idom, dj=dj, djf=dj.reshape(-1), paths=paths, choice=choice,
     )
 
 
@@ -395,7 +458,8 @@ def share_pq(ctx: EngineCtx, cfg, inst: InstView, tick) -> ShareResult:
     bg = background_load(ctx, tick)
     job_min_wire = jnp.full(J, BIG).at[ctx.inst_job].min(
         jnp.where(inst.active, inst.iwire, BIG))
-    is_hi = inst.active & (inst.iwire <= job_min_wire[ctx.inst_job])
+    is_hi = inst.active & (inst.iwire <=
+                           ctx.per_inst(job_min_wire[ctx.wl.job]))
     hi_rate = jnp.where(is_hi, inst.irate, 0.0)
     off_hi = inst.link_sum(ctx, hi_rate) + bg
     s_hi = jnp.minimum(1.0, st.cap / jnp.maximum(off_hi, 1.0))
@@ -403,8 +467,8 @@ def share_pq(ctx: EngineCtx, cfg, inst: InstView, tick) -> ShareResult:
     lo_rate = jnp.where(inst.active & ~is_hi, inst.irate, 0.0)
     off_lo = inst.link_sum(ctx, lo_rate)
     s_lo = rem / jnp.maximum(off_lo, 1.0)
-    share = jnp.where(is_hi[:, None], s_hi[inst.iroute],
-                      jnp.minimum(1.0, s_lo[inst.iroute]))
+    share = jnp.where(is_hi[:, None], inst.on_path(s_hi),
+                      jnp.minimum(1.0, inst.on_path(s_lo)))
     eff_scale = share.min(axis=1)
     return ShareResult(eff=w_rate * eff_scale, offered=off_hi + off_lo)
 
@@ -422,7 +486,7 @@ def share_wfq(ctx: EngineCtx, cfg, inst: InstView, tick) -> ShareResult:
     wsum = inst.link_sum(ctx, w_act)
     avail = jnp.maximum(st.cap - bg, 0.0)
     fair = avail / jnp.maximum(wsum, 1e-9)           # bytes/s per unit weight
-    allowed = wgt[:, None] * fair[inst.iroute]       # [FW, H]
+    allowed = wgt[:, None] * inst.on_path(fair)      # [FW, H]
     eff = jnp.minimum(w_rate, allowed.min(axis=1))
     return ShareResult(eff=eff, offered=inst.link_sum(ctx, w_rate) + bg)
 
@@ -475,9 +539,9 @@ def stage_marking(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
     """Combine RED with Symphony's selective marking along each path into
     the per-instance expected-mark accumulator lambda."""
     D = ctx.D
-    sm = state.s_stepmin[inst.dj]
-    pw = state.s_psnwin[inst.dj]
-    al = state.s_alpha[inst.dj]
+    sm = inst.on_path(state.s_stepmin, dj=True)
+    pw = inst.on_path(state.s_psnwin, dj=True)
+    al = inst.on_path(state.s_alpha, dj=True)
     # sym_on is a traced 0/1 gate (RuntimeKnobs): the marking math is always
     # in the program and selected at runtime, so one compile serves both the
     # baseline and the Symphony points of a knob grid.
@@ -486,7 +550,7 @@ def stage_marking(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
     p_sym = jnp.where(inst.idom < D, p_sym, 0.0)
     sym_gate = (jnp.asarray(cfg.sym_on) != 0) & (tick >= cfg.sym_start_tick)
     p_sym = jnp.where(sym_gate, p_sym, 0.0)
-    p_hop = 1.0 - (1.0 - p_red[inst.iroute]) * (1.0 - p_sym)
+    p_hop = 1.0 - (1.0 - inst.on_path(p_red)) * (1.0 - p_sym)
     log_nomark = jnp.sum(jnp.log1p(-jnp.minimum(p_hop, 0.999999)), axis=1)
     p_inst = 1.0 - jnp.exp(log_nomark)
     pkts = eff * cfg.dt / cfg.mtu
@@ -546,7 +610,9 @@ def stage_symphony(ctx: EngineCtx, cfg, state: EngineState, inst: InstView,
         jnp.where(act4 & ~done4, wire4, BIG))
     stepmin = jnp.where(min_act < BIG, jnp.minimum(cand, min_act), cand)
     psnwin = state.s_psnwin.at[djf].max(
-        jnp.where(send4 & ~done4 & (wire4 == stepmin[djf]), psn4, 0.0))
+        jnp.where(send4 & ~done4 &
+                  (wire4 == inst.on_path(stepmin, dj=True).reshape(-1)),
+                  psn4, 0.0))
 
     sym_epoch = (tick % cfg.sym_win_ticks) == (cfg.sym_win_ticks - 1)
     have = cnt > jnp.asarray(cfg.sym.n_sample, jnp.float32)

@@ -109,9 +109,10 @@ def compose_tick(ctx, cfg, state: EngineState, tick, starts, out: TickOut):
     """Compose the cheap stages around the fused hot-path outputs into the
     engine-tick contract ``(state', metric sample)``.  Shared between the
     per-tick path (XLA-side, around the pallas call) and the multi-tick
-    window kernel (replayed inside the kernel body per tick)."""
-    inst = instance_view(ctx, starts, state, cfg.mtu, cfg.per_step_ecmp,
-                         iroute=out.iroute)
+    window kernel (replayed inside the kernel body per tick).  The routes
+    come from :func:`instance_view`'s pick on the same ECMP hash the kernel
+    takes, so ``out.iroute`` is not read here."""
+    inst = instance_view(ctx, starts, state, cfg.mtu, cfg.per_step_ecmp)
     lam, _pkts, _sm = stage_marking(ctx, cfg, state, inst, out.p_red,
                                     out.eff, starts.lam, tick)
     sent, done_upto, finish, _newly_done = stage_progress(

@@ -118,7 +118,8 @@ def test_select_routes_per_step_rehash():
     # static: every instance of a flow uses the flow's route
     r_static = np.asarray(select_routes(ctx, np.zeros(ctx.FW, np.int32),
                                         per_step_ecmp=False))
-    assert (r_static == np.asarray(ctx.iroute_static)).all()
+    assert (r_static == np.repeat(np.asarray(ctx.st.routes), ctx.W,
+                                  axis=0)).all()
     # per-step: routes always come from the flow's candidate table
     table = np.asarray(ctx.st.path_table)
     for step in (0, 1, 7):

@@ -7,8 +7,14 @@ the packing contract: the packed slabs must round-trip **exactly** to the
 reference ``table[index]`` gathers across ECMP fan-outs, topologies, and
 non-dividing block tilings — plus the window-kernel state-donation and
 the benchmark-trajectory dedupe contracts that ride on the same PR.
+
+The staged engine's per-hop lookups go through flow-granular ``[F, P, H]``
+tables (`stages.PathIndex`) and an exact plane pick: those tests pin the
+pick to the per-instance gather it replaces, bit for bit, and the lowered
+tick's gathers to at most F*P*H indices each.
 """
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +26,8 @@ from repro.core.netsim import (SimParams, WorkloadBuilder, build_static,
 from repro.core.netsim.params import (PackedTables, pack_route_tables,
                                       plan_tiling)
 from repro.core.netsim.simulator import wl_arrays
-from repro.core.netsim.stages import init_state, make_ctx
+from repro.core.netsim.stages import (init_state, instance_view, make_ctx,
+                                      select_routes, stage_starts)
 
 
 def _ring_wl(n_hosts, ring):
@@ -106,6 +113,132 @@ def test_iota_select_matches_candidate_gather(build):
     assert np.array_equal(sel, np.asarray(st.path_table)[fl, ch])
     assert np.array_equal(sel_dom,
                           np.asarray(st.link_dom[st.path_table])[fl, ch])
+
+
+# ------------------------------ flow-granular lookups == per-instance ones
+def _random_view(ctx, per_step_ecmp, seed=11):
+    """The instance view over random window slots, ``-1`` (empty) among
+    them, and random completion counters."""
+    rng = np.random.default_rng(seed)
+    state = init_state(ctx, jax.random.PRNGKey(0))
+    step_of = rng.integers(-1, 40, (ctx.F, ctx.W)).astype(np.int32)
+    step_of[rng.random((ctx.F, ctx.W)) < 0.25] = -1
+    state = state._replace(done_upto=jnp.asarray(
+        rng.integers(0, 20, ctx.F), jnp.int32))
+    starts = stage_starts(ctx, state, 0)._replace(
+        step_of=jnp.asarray(step_of),
+        sent=jnp.asarray(rng.random((ctx.F, ctx.W)) * 3e5, jnp.float32))
+    return instance_view(ctx, starts, state, 1000.0, per_step_ecmp)
+
+
+def _assert_lookups_match_gathers(st, ctx, per_step_ecmp):
+    inst = _random_view(ctx, per_step_ecmp)
+    iroute = select_routes(ctx, inst.istep, per_step_ecmp)
+    dj = st.link_dom[iroute] * ctx.J + ctx.inst_job[:, None]
+    assert np.array_equal(np.asarray(inst.iroute), np.asarray(iroute))
+    assert np.array_equal(np.asarray(inst.dj), np.asarray(dj))
+    rng = np.random.default_rng(5)
+    DJ = (ctx.D + 1) * ctx.J
+    x_dj = jnp.asarray(rng.integers(-2**31, 2**31 - 1, DJ), jnp.int32)
+    x_link = rng.standard_normal(ctx.L + 1).astype(np.float32)
+    x_link[:3] = [np.nan, -0.0, np.inf]
+    x_link = jnp.asarray(x_link)
+    assert np.array_equal(np.asarray(inst.on_path(x_dj, dj=True)),
+                          np.asarray(x_dj[dj]))
+    # compare bit patterns: NaN payloads and the sign of zero included
+    assert np.array_equal(np.asarray(inst.on_path(x_link)).view(np.uint32),
+                          np.asarray(x_link[iroute]).view(np.uint32))
+    return inst
+
+
+@pytest.mark.parametrize("per_step_ecmp", [True, False],
+                         ids=["per_step_ecmp", "static_routes"])
+@pytest.mark.parametrize("build", TOPOS, ids=TOPO_IDS)
+def test_on_path_matches_per_instance_gather(build, per_step_ecmp):
+    """``InstView.on_path`` gathers once per (flow, candidate, hop) and
+    picks each instance's plane; the result is bitwise the per-instance
+    ``x[iroute]`` / ``x[dj]`` gather, for int and float arrays."""
+    st, ctx, _ = _ctx_for(build)
+    assert int(st.path_table.shape[1]) < ctx.W
+    inst = _assert_lookups_match_gathers(st, ctx, per_step_ecmp)
+    P = int(st.path_table.shape[1]) if per_step_ecmp else 1
+    assert inst.paths.links.shape == (ctx.F, P, ctx.H)
+    assert (inst.choice is None) == (not per_step_ecmp)
+
+
+def test_on_path_matches_per_instance_gather_when_window_is_narrow():
+    """With fewer window slots than candidates (W < P) the flow tables
+    gather more indices than the instances would, and the pick still
+    equals the per-instance gather, bit for bit."""
+    st, ctx, _ = _ctx_for(lambda: _leaf_spine(4), window=2)
+    assert ctx.W < int(st.path_table.shape[1])
+    _assert_lookups_match_gathers(st, ctx, True)
+
+
+def _gathers_by_scope(module):
+    """``[(innermost netsim.* scope or None, indices gathered)]`` for every
+    ``stablehlo.gather`` of a lowered module: an index per result element
+    outside the gather's offset (slice) dimensions."""
+    def walk(op):
+        for region in op.regions:
+            for block in region.blocks:
+                for o in block.operations:
+                    yield o
+                    yield from walk(o)
+
+    out = []
+    for o in walk(module.operation):
+        if o.name != "stablehlo.gather":
+            continue
+        scopes = re.findall(r"netsim\.[a-z_]+", str(o.location))
+        m = re.search(r"offset_dims = \[([0-9, ]*)\]",
+                      str(o.attributes["dimension_numbers"]))
+        offset = {int(d) for d in m.group(1).split(",")} if m else set()
+        shape = list(o.results[0].type.shape)
+        n = int(np.prod([d for i, d in enumerate(shape) if i not in offset]))
+        out.append((scopes[-1] if scopes else None, n))
+    return out
+
+
+@pytest.mark.parametrize("scenario, over", [
+    ("table1_ring", {}), ("fat_tree_multipod", {"n_hosts": 512})],
+    ids=["table1", "multipod512"])
+def test_tick_gathers_at_most_flow_candidate_hop_indices(scenario, over):
+    """One lane's staged XLA tick, lowered at the benchmark's widths with
+    traced knobs (so the Symphony marking and the ``pq_on`` branch are in
+    the program): no gather in the instance-view, share, marking or
+    Symphony stage reads more than F*P*H indices, and the per-hop lookups
+    read exactly that many."""
+    from benchmarks.common import build_scenario, knob_grid, sweep_axes_for
+    from repro.core.netsim.params import grid_from_params, merge_params
+    from repro.core.netsim.stages import engine_tick_xla
+
+    built = build_scenario(scenario, **over)
+    struct, knobs = grid_from_params(
+        knob_grid(built.cfg, sweep_axes_for(scenario))[:1])
+    assert struct.backend == "xla" and struct.per_step_ecmp
+    knobs = jax.tree.map(lambda x: x[0], knobs)
+    st = build_static(built.topo, built.wl, "ecmp", seed=0, dt=struct.dt,
+                      deploy=struct.deploy)
+    wla = wl_arrays(built.wl, struct.dt)
+
+    def tick(st, kn, key):
+        ctx = make_ctx(st, wla, struct.window)
+        return engine_tick_xla(ctx, merge_params(struct, kn),
+                               init_state(ctx, key), jnp.int32(3))
+
+    module = jax.jit(tick).lower(st, knobs, jax.random.PRNGKey(0)) \
+        .compiler_ir("stablehlo")
+    F, P, H = (int(d) for d in st.path_table.shape)
+    assert P < struct.window
+    staged = {"netsim.instance_view", "netsim.share", "netsim.marking",
+              "netsim.symphony"}
+    counts = [(s, n) for s, n in _gathers_by_scope(module) if s in staged]
+    assert max(n for _, n in counts) == F * P * H, counts
+    per_hop = sorted(s for s, n in counts if n == F * P * H)
+    # path_min + pq's two; p_red + the three Symphony rows; stepmin
+    assert per_hop == sorted(["netsim.share"] * 3 + ["netsim.marking"] * 4
+                             + ["netsim.symphony"]), per_hop
 
 
 @pytest.mark.parametrize("blk", [24, 40])

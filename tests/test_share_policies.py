@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.netsim.params import SimParams
+from repro.core.netsim.params import PathIndex, SimParams
 from repro.core.netsim.stages import (InstView, share_drr, share_proportional,
                                       share_wfq)
 
@@ -44,7 +44,12 @@ def _mini(routes, active, rate, cap, job=None, weight=None):
         complete=jnp.zeros(n, bool), active=jnp.asarray(active),
         iroute=jnp.asarray(routes), flat_links=jnp.asarray(routes.reshape(-1)),
         idom=jnp.zeros((n, h), jnp.int32), dj=jnp.zeros((n, h), jnp.int32),
-        djf=jnp.zeros(n * h, jnp.int32))
+        djf=jnp.zeros(n * h, jnp.int32),
+        # one flow per instance, one route plane each
+        paths=PathIndex(links=jnp.asarray(routes[:, None, :]),
+                        dom=jnp.zeros((n, 1, h), jnp.int32),
+                        dj=jnp.zeros((n, 1, h), jnp.int32)),
+        choice=None)
     return ctx, inst, cap_full, routes, job, weight
 
 
