@@ -6,7 +6,11 @@ found by name, so a later cell, configuration or metric is added as
 files and entries alone:
 
 * ``configs/<config>.json``: the deployment (fabric, job, engine
-  constants), read by the program's constructors and by the reference's;
+  constants); its ``fabric`` and ``job`` sections each name a ``kind``;
+* ``fabrics/<kind>.py``, ``jobs/<kind>.py``: the constructors of one
+  fabric or job kind, ``program(...)`` through the simulator's public
+  constructors (imported inside the function) and ``reference(...)``
+  with numpy alone, so the two sides share no code;
 * ``traffic/<traffic>.json``: the mix's parameters (traffic kind, knob
   axes, ticks per dispatch or window, engine path, chips, policy);
 * ``policies/<policy>.py``: an online action stream a mix names;
@@ -73,6 +77,16 @@ def load_cell(name: str, bench_file: Path = ROOT / "BENCHMARK.json",
         traffic=load_json(base / "traffic" / f"{w['traffic']}.json"),
         limits=load_json(base / "limits" / f"{name}.json"),
         end_to_end=e2e, per_layer=per_layer)
+
+
+def kind(section: str, spec: dict, base: Path = BENCH):
+    """``(module, arguments)`` of a configuration's ``fabric`` or ``job``
+    section: the file ``<section>/<kind>.py`` and the section's other
+    keys."""
+    args = dict(spec)
+    name = args.pop("kind")
+    path = base / section / f"{name}.py"
+    return load_module(path, f"{section}.{name}"), args
 
 
 def metric_reader(name: str, base: Path = BENCH):

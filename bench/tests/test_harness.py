@@ -5,6 +5,8 @@ trace; and a whole run at a tiny size prints a last line of the
 expected shape."""
 from __future__ import annotations
 
+import dataclasses
+import functools
 import io
 import json
 import shutil
@@ -16,6 +18,7 @@ import pytest
 from lib import cells, harness, trace as tr
 from lib.chip import CompileClock
 from lib.roofline import peak, tick_bytes
+import tiny
 from tiny import BENCH, cell, now
 
 FIXTURE = BENCH / "tests" / "fixtures" / "online_trace.json"
@@ -53,19 +56,17 @@ def test_cell_and_metric_found_by_name(tmp_path):
     assert cells.metric_reader("dummy.metric", base)(None) == 42.0
 
 
-@pytest.mark.parametrize("name", ["table1_leafspine32", "multipod512"])
-def test_reference_and_program_build_the_same_deployment(name):
-    """The reference's own constructors and the simulator's agree on every
-    array the tick reads, at the configuration's full size."""
+def _same_deployment(cfg, base=BENCH):
+    """The kind files' reference constructors and the simulator's agree on
+    every array the tick reads."""
     from lib.deploy import program_inputs
     from reference.build import deployment
     from repro.core.netsim.simulator import build_static, wl_arrays
 
-    cfg = cells.load_json(BENCH / "configs" / f"{name}.json")
-    topo, wl = program_inputs(cfg)
+    topo, wl = program_inputs(cfg, base)
     st = build_static(topo, wl, "ecmp", 5, dt=cfg["engine"]["dt"])
     wla = wl_arrays(wl, cfg["engine"]["dt"])
-    dep = deployment(cfg)
+    dep = deployment(cfg, base)
     same = lambda a, b: np.array_equal(np.asarray(a), np.asarray(b))
     assert same(st.path_table, dep.paths) and same(st.n_paths, dep.n_paths)
     assert same(st.cap, dep.cap.astype(np.float32))
@@ -73,9 +74,19 @@ def test_reference_and_program_build_the_same_deployment(name):
     for f in ("pred", "job", "phase", "pass_steps", "total_steps",
               "n_phases", "n_segs", "step_offset", "trig_job", "trig_seg"):
         assert same(getattr(wla, f), getattr(dep, f)), f
-    assert same(wla.sps, dep.sps) and same(wla.fstart_ticks, dep.fstart)
+    for f, g in (("sps", "sps"), ("fstart_ticks", "fstart"),
+                 ("gap_ticks", "gap"), ("start_ticks", "seg_ready0"),
+                 ("trig_delay_ticks", "trig_delay")):
+        assert same(getattr(wla, f), getattr(dep, g)), f
     assert same(wla.chunk_sched, dep.chunk.astype(np.float32))
     assert same(st.cap[st.routes[:, 0]], dep.line_rate.astype(np.float32))
+
+
+@pytest.mark.parametrize("path", sorted(BENCH.glob("configs/*.json")),
+                         ids=lambda p: p.stem)
+def test_reference_and_program_build_the_same_deployment(path):
+    """At the configuration's full size."""
+    _same_deployment(cells.load_json(path))
 
 
 def test_every_cell_of_the_benchmark_loads():
@@ -155,8 +166,16 @@ def test_leaves_drop_enclosing_loops():
     assert tr.union([(0, 10), (5, 20), (30, 40)]) == [(0, 20), (30, 40)]
 
 
-@pytest.mark.parametrize("name", ["table1.sweep18.kernel",
-                                  "table1.online.kernel"])
+def _one_cell_per_kind() -> list[str]:
+    """The first cell of each (configuration, traffic kind) pair."""
+    first = {}
+    for w in cells.load_json(cells.ROOT / "BENCHMARK.json")["workloads"]:
+        tr = cells.load_json(BENCH / "traffic" / f"{w['traffic']}.json")
+        first.setdefault((w["config"], tr["kind"]), w["name"])
+    return list(first.values())
+
+
+@pytest.mark.parametrize("name", _one_cell_per_kind())
 def test_tiny_run_prints_a_result_line(name):
     out, err = io.StringIO(), io.StringIO()
     c = cell(name)
@@ -174,3 +193,76 @@ def test_tiny_run_prints_a_result_line(name):
                                    "memory_peak_bytes"}
     tail = err.getvalue().strip().splitlines()[-len(c.limits):]
     assert all(line.startswith("check ") for line in tail)
+
+
+THREE_TENANTS = dict(kind="three_tenants", chunks=[100000.0, 50000.0],
+                     gap_s=2e-4, delay_s=1e-4, start_s=3e-4)
+
+
+@pytest.fixture
+def tenants(tmp_path, monkeypatch):
+    """A configuration whose job kind lives only in ``tmp_path/jobs``,
+    loaded through ``load_cell`` and cut to its own ``cpu_test``: three
+    tenants on the 8-host leaf-spine, 4 lanes of 600 ticks on the XLA
+    engine under the Table-1 sweep's limits.  The run's mix builds both
+    sides from ``tmp_path``."""
+    from lib import kinds
+    from lib.deploy import program_inputs
+    from reference.build import deployment
+
+    base = tmp_path / "bench"
+    for d in ("configs", "traffic", "limits", "fabrics", "jobs"):
+        (base / d).mkdir(parents=True)
+    shutil.copy(FIXTURE.parent / "three_tenants.py", base / "jobs")
+    shutil.copy(BENCH / "fabrics" / "leaf_spine.py", base / "fabrics")
+    shutil.copy(BENCH / "traffic" / "sweep18_kernel.json", base / "traffic")
+    shutil.copy(BENCH / "limits" / "table1.sweep18.kernel.json",
+                base / "limits" / "tenants.sweep.json")
+    cfg = cells.load_json(BENCH / "configs" / "table1_leafspine32.json")
+    cfg.update(job=THREE_TENANTS,
+               cpu_test={"fabric": tiny.FABRIC, "job": THREE_TENANTS})
+    (base / "configs" / "tenants.json").write_text(json.dumps(cfg))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps({
+        "workloads": [{"name": "tenants.sweep", "config": "tenants",
+                       "traffic": "sweep18_kernel", "chips": 1}],
+        "end_to_end": [{"name": "lane_ticks_per_s", "unit": "lane-ticks/s"},
+                       {"name": "setup_s", "unit": "s"}],
+        "per_layer": []}))
+    c = cell("tenants.sweep", bench_file=tmp_path / "BENCHMARK.json",
+             base=base)
+    c.config["horizon_ticks"] = 600
+    monkeypatch.setattr(kinds, "program_inputs",
+                        functools.partial(program_inputs, base=base))
+    monkeypatch.setattr(kinds, "deployment",
+                        functools.partial(deployment, base=base))
+    return c, base
+
+
+def _run(c) -> dict:
+    return harness.run(c, 2**31 + 5, 0.5, False, jax.devices()[:1], now(),
+                       CompileClock(), out=io.StringIO(), err=io.StringIO())
+
+
+def test_a_kind_found_by_name_builds_on_both_sides(tenants):
+    c, base = tenants
+    _same_deployment(c.config, base)
+    res = _run(c)
+    assert res["correct"] and res["check"]["int_mismatch"]["value"] == 0
+
+
+def test_a_kind_found_by_name_sees_a_trigger_one_tick_late(tenants,
+                                                           monkeypatch):
+    """The control: the reference releases the triggered tenant one tick
+    later than the program does."""
+    from lib import kinds
+    from reference.build import deployment
+
+    c, base = tenants
+
+    def late(cfg):
+        dep = deployment(cfg, base)
+        return dataclasses.replace(dep, trig_delay=dep.trig_delay + 1)
+
+    monkeypatch.setattr(kinds, "deployment", late)
+    res = _run(c)
+    assert not res["correct"] and res["check"]["int_mismatch"]["value"] > 0

@@ -1,11 +1,13 @@
 """Small cells for the CPU tests: the real configurations' engine
-constants and limits on an 8-host fabric and a short horizon."""
+constants and limits on a small fabric and a short horizon.  A
+configuration's ``cpu_test`` section names its own ``fabric`` and
+``job`` at a CPU test's size; without one, an 8-host ring stands in."""
 from __future__ import annotations
 
 import copy
 import time
 
-from lib.cells import BENCH, Cell, load_cell, load_json
+from lib.cells import BENCH, ROOT, Cell, load_cell, load_json
 
 FABRIC = dict(kind="leaf_spine", n_hosts=8, n_tors=2, n_spines=2,
               host_gbps=10, fabric_gbps=10)
@@ -13,12 +15,16 @@ JOB = dict(kind="ring_allreduce", ring=4, chunk_bytes=200000.0, passes=1)
 
 
 def cell(name: str, lanes_axes=None, backend: str = "xla",
-         chips: int | None = None) -> Cell:
-    """The cell ``name`` of ``BENCHMARK.json`` cut to a CPU test's size:
-    8 hosts, 100-tick dispatches or 40-tick windows, the XLA engine (the
-    kernel runs interpreted on a CPU)."""
-    c = copy.deepcopy(load_cell(name))
-    c.config.update(fabric=dict(FABRIC), job=dict(JOB), horizon_ticks=100)
+         chips: int | None = None, bench_file=ROOT / "BENCHMARK.json",
+         base=BENCH) -> Cell:
+    """The cell ``name`` of ``bench_file`` cut to a CPU test's size: the
+    configuration's ``cpu_test`` fabric and job, 100-tick dispatches or
+    40-tick windows, the XLA engine (the kernel runs interpreted on a
+    CPU)."""
+    c = copy.deepcopy(load_cell(name, bench_file, base))
+    small = c.config.get("cpu_test", {"fabric": FABRIC, "job": JOB})
+    c.config.update(fabric=dict(small["fabric"]), job=dict(small["job"]),
+                    horizon_ticks=100)
     tr = c.traffic
     tr["path"] = {"backend": backend}
     tr["trace_units"] = 1
